@@ -35,8 +35,9 @@ import pathlib
 import subprocess
 import time
 
-import jax
 import numpy as np
+
+from repro.runtime.jax_env import device_info, enable_compile_cache
 
 # --check fails any mix whose continuous/serial tokens-per-second ratio is
 # below this (1.0 = "not slower"; headroom left for noisy shared runners is
@@ -161,6 +162,9 @@ def main():
                     help="fail unless continuous tokens/s >= %.2fx serial "
                          "at every mix" % MIN_SPEEDUP)
     args = ap.parse_args()
+    enable_compile_cache()
+    device = device_info()
+    print(f"device: {device}")
 
     rows, speedups = bench_dispatch(args.streams, args.waves,
                                     args.decode_tokens, args.stagger_steps,
@@ -187,7 +191,7 @@ def main():
                        "decode_tokens": args.decode_tokens,
                        "stagger_steps": args.stagger_steps,
                        "n_slots": args.n_slots,
-                       "backend": jax.default_backend()},
+                       "device": device},
             "benchmarks": [
                 {"name": name, "us_per_call": round(us, 2), "derived": derived}
                 for name, us, derived in rows
@@ -210,7 +214,7 @@ def main():
         hist = root / "BENCH_history.jsonl"
         line = {"commit": commit, "bench": "dispatch",
                 "date": time.strftime("%Y-%m-%d"),
-                "backend": jax.default_backend(), "headline": headline}
+                "device": device, "headline": headline}
         with hist.open("a") as f:
             f.write(json.dumps(line) + "\n")
         print(f"appended {hist}")

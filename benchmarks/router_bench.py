@@ -44,17 +44,17 @@ Prints ``name,us_per_call,derived`` CSV lines (the repo benchmark contract):
                            measured, not assumed
   sweep/route_step_sharded@M{m} / sweep/route_step_hier@M{m}
                          — ``--sharded-sweep`` rows: the whole compiled
-                           sharded serve round on a FAKED 8-device host mesh
-                           (subprocess — the device count locks at jax init),
-                           gathered tail vs the hierarchical O(n_devices)
-                           tail, so the claim that killing the per-round
-                           O(M) all-gather does not cost latency is a
-                           checked-in measured number (``vs_gathered`` in
-                           the hier rows' derived field)
+                           sharded serve round on a mesh over every device
+                           of this one process (``jax.devices()``), gathered
+                           tail vs the hierarchical O(n_devices) tail
+                           (``vs_gathered`` in the hier rows' derived field).
+                           A CPU rehearsal gets virtual devices from the
+                           shell that starts it:
+                           ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
 
 With ``--json`` the same rows are written to ``BENCH_router.json`` so every
 PR records the perf trajectory (CI uploads it as an artifact), and a
-one-line snapshot (commit, date, backend, headline router/ and sweep rows)
+one-line snapshot (commit, date, device, headline router/ and sweep rows)
 is appended to ``BENCH_history.jsonl`` — the append-only per-PR perf log
 that survives baseline refreshes overwriting the JSON.  With
 ``--check PATH`` the run becomes a regression gate: any benchmark more than
@@ -66,7 +66,6 @@ from __future__ import annotations
 
 import argparse
 import json
-import os
 import pathlib
 import subprocess
 import sys
@@ -75,6 +74,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.runtime.jax_env import device_info, enable_compile_cache
 
 # --check fails any benchmark this much slower than its baseline row
 REGRESSION_FACTOR = 2.0
@@ -350,19 +351,20 @@ def bench_streams_sweep(sweep, steps: int):
     return rows
 
 
-def bench_sharded_child(sweep, rounds: int, iters: int):
-    """Runs INSIDE the faked-device subprocess: one compiled sharded serve
-    scan per (M, tail-mode) cell, gathered vs hierarchical, µs per round.
-    The pools are sized 2/1 servers per device so the hierarchical static
-    partition divides evenly at any device count."""
+def bench_sharded(sweep, rounds: int, iters: int):
+    """One compiled sharded serve scan per (M, tail-mode) cell on a mesh
+    over all of this process's devices, gathered vs hierarchical, µs per
+    round.  The pools are sized 2/1 servers per device so the hierarchical
+    static partition divides evenly at any device count."""
     from repro.core.cost_model import SystemConfig
     from repro.serving.policy import make_policy
     from repro.serving.session import ServeSession
     from repro.serving.simulator import SimConfig, Simulator
+    from repro.sharding.compat import make_mesh
 
     sys_ = SystemConfig()
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_mesh((n_dev,), ("data",))
     pol = make_policy("r2evid", sys_)
     rows = []
     for m in sweep:
@@ -391,34 +393,6 @@ def bench_sharded_child(sweep, rounds: int, iters: int):
                      f"streams={m},devices={n_dev},"
                      f"us_per_segment={us_h / m:.3f},"
                      f"vs_gathered={us_h / max(us_g, 1e-9):.3f}x"))
-    return rows
-
-
-def bench_sharded(sweep_csv: str, rounds: int, steps: int, n_dev: int = 8):
-    """Spawn the faked-``n_dev``-device child (the device count locks at
-    first jax init, so the parent process cannot fake it itself) and parse
-    its CSV rows back into the parent's row list."""
-    env = dict(
-        os.environ,
-        XLA_FLAGS=f"--xla_force_host_platform_device_count={n_dev}",
-        JAX_PLATFORMS="cpu",
-    )
-    cmd = [sys.executable, __file__, "--_sharded-child",
-           "--sharded-sweep", sweep_csv, "--scan-rounds", str(rounds),
-           "--steps", str(steps)]
-    out = subprocess.run(cmd, capture_output=True, text=True, env=env,
-                         timeout=3600)
-    if out.returncode != 0:
-        raise RuntimeError("sharded bench child failed:\n"
-                           + out.stderr[-3000:])
-    rows = []
-    for line in out.stdout.splitlines():
-        if line.startswith("sweep/route_step_"):
-            name, us, derived = line.split(",", 2)
-            rows.append((name, float(us), derived))
-    if not rows:
-        raise RuntimeError("sharded bench child produced no rows:\n"
-                           + out.stdout[-2000:])
     return rows
 
 
@@ -527,23 +501,17 @@ def main():
                          "the M=512 rows CI checks against)")
     ap.add_argument("--sharded-sweep", default="256,1024,4096",
                     help="comma-separated stream counts for the sharded "
-                         "serve rows on a faked 8-device host mesh (empty "
-                         "string disables; runs in a subprocess)")
-    ap.add_argument("--_sharded-child", dest="_sharded_child",
-                    action="store_true", help=argparse.SUPPRESS)
+                         "serve rows on a mesh over jax.devices() (empty "
+                         "string disables)")
     ap.add_argument("--json", action="store_true",
                     help="also write BENCH_router.json next to the repo root")
     ap.add_argument("--check", metavar="BASELINE",
                     help="fail if any benchmark is >%.0fx slower than the "
                          "same-named row in this baseline JSON" % REGRESSION_FACTOR)
     args = ap.parse_args()
-
-    if args._sharded_child:
-        sweep = [int(s) for s in args.sharded_sweep.split(",")]
-        for name, us, derived in bench_sharded_child(
-                sweep, args.scan_rounds, max(args.steps // 6, 3)):
-            print(f"{name},{us:.3f},{derived}")
-        return
+    enable_compile_cache()
+    device = device_info()
+    print(f"device: {device}")
 
     rows = []
     rows += bench_route_step(args.streams, args.steps)
@@ -555,8 +523,9 @@ def main():
         sweep = [int(s) for s in args.streams_sweep.split(",")]
         rows += bench_streams_sweep(sweep, args.steps)
     if args.sharded_sweep:
-        rows += bench_sharded(args.sharded_sweep, args.scan_rounds,
-                              args.steps)
+        sweep = [int(s) for s in args.sharded_sweep.split(",")]
+        rows += bench_sharded(sweep, args.scan_rounds,
+                              max(args.steps // 6, 3))
 
     print("name,us_per_call,derived")
     for name, us, derived in rows:
@@ -569,7 +538,7 @@ def main():
             "config": {"streams": args.streams, "steps": args.steps,
                        "tasks": args.tasks, "scan_rounds": args.scan_rounds,
                        "streams_sweep": args.streams_sweep,
-                       "backend": jax.default_backend()},
+                       "device": device},
             "benchmarks": [
                 {"name": name, "us_per_call": round(us, 2), "derived": derived,
                  "calls_per_s": round(1e6 / max(us, 1e-9), 1)}
@@ -596,7 +565,7 @@ def main():
             commit = "unknown"
         snap = {"commit": commit,
                 "date": time.strftime("%Y-%m-%dT%H:%M:%S"),
-                "backend": jax.default_backend(),
+                "device": device,
                 "config": out["config"], "headline": headline}
         hist = root / "BENCH_history.jsonl"
         with hist.open("a") as f:

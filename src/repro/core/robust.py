@@ -212,8 +212,8 @@ def solve_ccg(prob: RobustProblem, difficulty, acc_req, max_iters: int = 8,
     table-free ``ccg_encode`` kernel (accuracy formula → feasibility bitmask
     → recourse slab in one pass; no (M, F, K) tensor anywhere).  ``force``
     pins both the encode and master implementations for tests: "pallas"
-    (interpret off-TPU) / "ref" exercise the kernel ops, "auto" picks the
-    backend default.
+    (compiled), "interpret" (the Pallas interpreter) and "ref" exercise the
+    kernel ops, "auto" picks the backend default.
 
     ``warm_y``: optional (M,) flat first-stage warm starts (the Stage-1
     route).  When given, each task's scenario set is seeded with the exact

@@ -18,14 +18,7 @@ Validated in interpret mode on CPU; compiled natively on TPU.
                      candidates: draw, accuracies, reclaimable gain)
 
 See README.md in this directory for the kernel-family map and the
-ref-vs-Pallas dispatch rules (``force=`` pins).
+ref-vs-Pallas dispatch rules (``force=`` pins).  Import each entry point from
+its package's ``ops`` module: this package re-exports nothing, so importing
+``repro.kernels.dispatch`` pulls in no kernel.
 """
-from repro.kernels.c6_tail.ops import c6_tail  # noqa: F401
-from repro.kernels.ccg_encode.ops import ccg_encode  # noqa: F401
-from repro.kernels.ccg_master.ops import ccg_master  # noqa: F401
-from repro.kernels.ccg_solve.ops import ccg_solve  # noqa: F401
-from repro.kernels.decode_attention.ops import decode_attention  # noqa: F401
-from repro.kernels.flash_attention.ops import flash_attention  # noqa: F401
-from repro.kernels.mamba_scan.ops import selective_scan  # noqa: F401
-from repro.kernels.rglru.ops import rglru_scan  # noqa: F401
-from repro.kernels.temporal_gate.ops import gate_cell  # noqa: F401

@@ -1,4 +1,5 @@
-"""jit'd public wrapper: dispatches Pallas on TPU, interpret/ref elsewhere."""
+"""jit'd public wrapper: dispatches the compiled Pallas kernel on TPU and
+the jnp ref elsewhere (``repro.kernels.dispatch``)."""
 from __future__ import annotations
 
 from functools import partial
@@ -8,10 +9,7 @@ import jax.numpy as jnp
 
 from repro.kernels.c6_tail.kernel import c6_tail as _pallas
 from repro.kernels.c6_tail.ref import c6_tail_ref as _ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.dispatch import pallas_interpret
 
 
 @partial(jax.jit, static_argnames=("n_fps", "block_m", "force"))
@@ -23,31 +21,22 @@ def c6_tail(bw_panel, r, p, v, route, z, acc_thr, rn, pn, *, n_fps: int,
     decision indices; z: (M,) difficulty; acc_thr: (M,) accuracy floor
     (A^q + margin); rn/pn: (N,)/(Z,) normalized coordinates.
 
-    ``force``: "auto" picks Pallas on TPU and the jnp ref elsewhere;
-    "pallas"/"ref" override (Pallas runs in interpret mode off-TPU).  M is
+    ``force``: see :func:`repro.kernels.dispatch.pallas_interpret`.  M is
     padded up to the kernel block; padded lanes read panel row 0 with r=p=0
     (no demotion possible, gain -BIG) and are sliced off.
     """
-    if force == "ref" or (force == "auto" and not _on_tpu()):
-        bw, gain, can_p = _ref(bw_panel, r, p, v, route, z, acc_thr, rn, pn,
-                               n_fps)
-        return bw, gain, can_p
+    interpret = pallas_interpret(force)
+    if interpret is None:
+        return _ref(bw_panel, r, p, v, route, z, acc_thr, rn, pn, n_fps)
     m = bw_panel.shape[0]
     bm = min(block_m, m)
     pad_m = (-m) % bm
-    if pad_m:
-        bw_panel = jnp.pad(bw_panel, ((0, pad_m), (0, 0)))
-        r = jnp.pad(r, (0, pad_m))
-        p = jnp.pad(p, (0, pad_m))
-        v = jnp.pad(v, (0, pad_m))
-        route = jnp.pad(route, (0, pad_m))
-        z = jnp.pad(z, (0, pad_m))
-        acc_thr = jnp.pad(acc_thr, (0, pad_m))
+    col = lambda x, dt: jnp.pad(x.astype(dt), (0, pad_m))[:, None]
     bw, gain, can_p = _pallas(
-        bw_panel.astype(jnp.float32), r.astype(jnp.int32), p.astype(jnp.int32),
-        v.astype(jnp.int32), route.astype(jnp.int32), z.astype(jnp.float32),
-        acc_thr.astype(jnp.float32), rn.astype(jnp.float32),
-        pn.astype(jnp.float32), n_fps=n_fps, block_m=bm,
-        interpret=not _on_tpu(),
+        jnp.pad(bw_panel.astype(jnp.float32), ((0, pad_m), (0, 0))),
+        col(r, jnp.int32), col(p, jnp.int32), col(v, jnp.int32),
+        col(route, jnp.int32), col(z, jnp.float32), col(acc_thr, jnp.float32),
+        rn.astype(jnp.float32)[None, :], pn.astype(jnp.float32)[None, :],
+        n_fps=n_fps, block_m=bm, interpret=interpret,
     )
-    return bw[:m], gain[:m], can_p[:m] > 0
+    return bw[:m, 0], gain[:m, 0], can_p[:m, 0] > 0

@@ -1,4 +1,5 @@
-"""jit'd public wrapper: dispatches Pallas on TPU, interpret/ref elsewhere."""
+"""jit'd public wrapper: dispatches the compiled Pallas kernel on TPU and
+the jnp ref elsewhere (``repro.kernels.dispatch``)."""
 from __future__ import annotations
 
 from functools import partial
@@ -8,10 +9,7 @@ import jax.numpy as jnp
 
 from repro.kernels.ccg_encode.kernel import ccg_encode as _pallas
 from repro.kernels.ccg_encode.ref import ccg_encode_ref as _ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.dispatch import pallas_interpret
 
 
 @partial(jax.jit, static_argnames=("margin", "num_versions", "block_m", "force"))
@@ -30,29 +28,24 @@ def ccg_encode(z, aq, rn_flat, pn_flat, tier_flat, b2_scaled, rec_table, *,
     (M, F) int32 feasible-version bitmask, the (M, P, F) recourse slab, and
     the (M,) flat accuracy argmax used by the all-infeasible fallback.
 
-    ``force``: "auto" picks Pallas on TPU and the jnp ref elsewhere;
-    "pallas"/"ref" override (Pallas runs in interpret mode off-TPU).  M is
+    ``force``: see :func:`repro.kernels.dispatch.pallas_interpret`.  M is
     padded up to the kernel block, so any batch size works.
     """
-    if force == "ref" or (force == "auto" and not _on_tpu()):
+    interpret = pallas_interpret(force)
+    if interpret is None:
         return _ref(z, aq, rn_flat, pn_flat, tier_flat, rec_table,
                     margin, num_versions, y_ok=y_ok)
     m = z.shape[0]
     bm = min(block_m, m)
     pad_m = (-m) % bm
-    if pad_m:
-        z = jnp.pad(z, (0, pad_m))
-        aq = jnp.pad(aq, (0, pad_m))
-    ok = (jnp.ones_like(rn_flat) if y_ok is None else jnp.asarray(y_ok))
+    col = lambda x: jnp.pad(x.astype(jnp.float32), (0, pad_m))[:, None]
+    row = lambda x: jnp.asarray(x, jnp.float32)[None, :]
+    ok = jnp.ones_like(rn_flat) if y_ok is None else y_ok
     code, rec_all, best = _pallas(
-        z.astype(jnp.float32),
-        aq.astype(jnp.float32),
-        rn_flat.astype(jnp.float32),
-        pn_flat.astype(jnp.float32),
-        tier_flat.astype(jnp.float32),
-        ok.astype(jnp.float32),
+        col(z), col(aq),
+        row(rn_flat), row(pn_flat), row(tier_flat), row(ok),
         jnp.moveaxis(b2_scaled, -1, 0).astype(jnp.float32),   # (K, P, F)
         margin=margin, num_versions=num_versions, block_m=bm,
-        interpret=not _on_tpu(),
+        interpret=interpret,
     )
-    return code[:m], rec_all[:m], best[:m]
+    return code[:m], rec_all[:m], best[:m, 0]

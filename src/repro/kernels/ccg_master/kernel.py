@@ -29,20 +29,21 @@ def _master_kernel(rec_ref, mask_ref, fsok_ref, c1_ref, y_ref, od_ref):
     bm, _, bf = rec_ref.shape
 
     mask = mask_ref[...]                                   # (bm, P)
-    any_scen = mask.sum(axis=1) > 0.0                      # (bm,)
+    any_scen = mask.sum(axis=1, keepdims=True) > 0.0       # (bm, 1)
     active = jnp.where(mask[:, :, None] > 0.0, rec_ref[...], -BIG)
-    eta = jnp.where(any_scen[:, None], active.max(axis=1), 0.0)   # (bm, bf)
-    obj = jnp.where(fsok_ref[...] > 0.0, c1_ref[...][None, :] + eta, BIG)
+    eta = jnp.where(any_scen, active.max(axis=1), 0.0)     # (bm, bf)
+    obj = jnp.where(fsok_ref[...] > 0.0, c1_ref[...] + eta, BIG)
 
     # first-min argmin for this tile, in global F coordinates
     idx = jax.lax.broadcasted_iota(jnp.int32, (bm, bf), 1) + fi * bf
-    tile_min = obj.min(axis=1)                             # (bm,)
-    tile_arg = jnp.where(obj == tile_min[:, None], idx, _INT_MAX).min(axis=1)
+    tile_min = obj.min(axis=1, keepdims=True)              # (bm, 1)
+    tile_arg = jnp.where(obj == tile_min, idx, _INT_MAX).min(axis=1,
+                                                             keepdims=True)
 
     @pl.when(fi == 0)
     def _():
-        od_ref[...] = jnp.full((bm,), BIG, od_ref.dtype)
-        y_ref[...] = jnp.zeros((bm,), y_ref.dtype)
+        od_ref[...] = jnp.full((bm, 1), BIG, od_ref.dtype)
+        y_ref[...] = jnp.zeros((bm, 1), y_ref.dtype)
 
     best = od_ref[...]
     better = tile_min < best                               # strict: first min wins
@@ -53,9 +54,10 @@ def _master_kernel(rec_ref, mask_ref, fsok_ref, c1_ref, y_ref, od_ref):
 def ccg_master(rec_all, scen_mask, fs_ok, c1, *, block_m: int = 128,
                block_f: int = 128, interpret: bool = False):
     """rec_all: (M, P, F); scen_mask: (M, P); fs_ok: (M, F) float 0/1;
-    c1: (F,) -> (y_star (M,) int32, o_down (M,) float32).
+    c1: (1, F) -> (y_star (M, 1) int32, o_down (M, 1) float32).
 
-    M must divide block_m and F divide block_f (the ops wrapper pads).
+    The per-task outputs are (M, 1) columns (every block 2-D or 3-D).  M
+    must divide block_m and F divide block_f (the ops wrapper pads).
     """
     m, p, f = rec_all.shape
     bm = min(block_m, m)
@@ -63,6 +65,7 @@ def ccg_master(rec_all, scen_mask, fs_ok, c1, *, block_m: int = 128,
     assert m % bm == 0 and f % bf == 0
     grid = (m // bm, f // bf)
 
+    col = lambda: pl.BlockSpec((bm, 1), lambda mi, fi: (mi, 0))
     return pl.pallas_call(
         _master_kernel,
         grid=grid,
@@ -70,15 +73,12 @@ def ccg_master(rec_all, scen_mask, fs_ok, c1, *, block_m: int = 128,
             pl.BlockSpec((bm, p, bf), lambda mi, fi: (mi, 0, fi)),
             pl.BlockSpec((bm, p), lambda mi, fi: (mi, 0)),
             pl.BlockSpec((bm, bf), lambda mi, fi: (mi, fi)),
-            pl.BlockSpec((bf,), lambda mi, fi: (fi,)),
+            pl.BlockSpec((1, bf), lambda mi, fi: (0, fi)),
         ],
-        out_specs=[
-            pl.BlockSpec((bm,), lambda mi, fi: (mi,)),
-            pl.BlockSpec((bm,), lambda mi, fi: (mi,)),
-        ],
+        out_specs=[col(), col()],
         out_shape=[
-            jax.ShapeDtypeStruct((m,), jnp.int32),
-            jax.ShapeDtypeStruct((m,), jnp.float32),
+            jax.ShapeDtypeStruct((m, 1), jnp.int32),
+            jax.ShapeDtypeStruct((m, 1), jnp.float32),
         ],
         interpret=interpret,
     )(rec_all, scen_mask, fs_ok, c1)

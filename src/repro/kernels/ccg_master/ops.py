@@ -1,4 +1,5 @@
-"""jit'd public wrapper: dispatches Pallas on TPU, interpret/ref elsewhere."""
+"""jit'd public wrapper: dispatches the compiled Pallas kernel on TPU and
+the jnp ref elsewhere (``repro.kernels.dispatch``)."""
 from __future__ import annotations
 
 from functools import partial
@@ -8,10 +9,7 @@ import jax.numpy as jnp
 
 from repro.kernels.ccg_master.kernel import ccg_master as _pallas
 from repro.kernels.ccg_master.ref import ccg_master_ref as _ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.dispatch import pallas_interpret
 
 
 @partial(jax.jit, static_argnames=("block_m", "block_f", "force"))
@@ -20,13 +18,13 @@ def ccg_master(rec_all, scen_mask, fs_ok, c1, *, block_m: int = 128,
     """Masked CCG master step for a task batch -> (y_star, o_down).
 
     rec_all: (M, P, F); scen_mask: (M, P) 0/1; fs_ok: (M, F) bool; c1: (F,).
-    ``force``: "auto" picks Pallas on TPU and the jnp ref elsewhere;
-    "pallas"/"ref" override (Pallas runs in interpret mode off-TPU).  Both
+    ``force``: see :func:`repro.kernels.dispatch.pallas_interpret`.  Both
     M and F are padded up to the kernel blocks, so any shape works: padded
     options are infeasible (they never win the argmin) and padded tasks are
     sliced off.
     """
-    if force == "ref" or (force == "auto" and not _on_tpu()):
+    interpret = pallas_interpret(force)
+    if interpret is None:
         return _ref(rec_all, scen_mask, fs_ok, c1)
     m, p, f = rec_all.shape
     bm = min(block_m, m)
@@ -42,7 +40,7 @@ def ccg_master(rec_all, scen_mask, fs_ok, c1, *, block_m: int = 128,
         rec_all.astype(jnp.float32),
         scen_mask.astype(jnp.float32),
         fs_ok.astype(jnp.float32),
-        c1.astype(jnp.float32),
-        block_m=bm, block_f=bf, interpret=not _on_tpu(),
+        c1.astype(jnp.float32)[None, :],
+        block_m=bm, block_f=bf, interpret=interpret,
     )
-    return y[:m], o_down[:m]
+    return y[:m, 0], o_down[:m, 0]

@@ -1,4 +1,5 @@
-"""jit'd public wrapper: dispatches Pallas on TPU, interpret/ref elsewhere."""
+"""jit'd public wrapper: dispatches the compiled Pallas kernel on TPU and
+the jnp ref elsewhere (``repro.kernels.dispatch``)."""
 from __future__ import annotations
 
 from functools import partial
@@ -8,10 +9,7 @@ import jax.numpy as jnp
 
 from repro.kernels.ccg_solve.kernel import ccg_solve as _pallas
 from repro.kernels.ccg_solve.ref import ccg_solve_ref as _ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.dispatch import pallas_interpret
 
 
 @partial(jax.jit, static_argnames=("margin", "num_versions", "max_iters",
@@ -31,32 +29,31 @@ def ccg_solve(z, aq, rn_flat, pn_flat, tier_flat, b2_flat, u_all, c1_flat,
     SP pole selection -> η update across all min(max_iters, P+1) CCG steps in
     one pass — no per-step dispatch, no (M, P, F) recourse slab.
 
-    ``force``: "auto" picks Pallas on TPU and the jnp ref elsewhere;
-    "pallas"/"ref" override (Pallas runs in interpret mode off-TPU).  M is
+    ``force``: see :func:`repro.kernels.dispatch.pallas_interpret`.  M is
     padded up to the kernel block; padded lanes are cold, all-infeasible-safe
     dummies sliced off before returning.
     """
-    if force == "ref" or (force == "auto" and not _on_tpu()):
+    interpret = pallas_interpret(force)
+    if interpret is None:
         return _ref(z, aq, rn_flat, pn_flat, tier_flat, b2_flat, u_all,
                     c1_flat, warm_y, margin, num_versions, max_iters, theta,
                     y_ok=y_ok)
     m = z.shape[0]
     bm = min(block_m, m)
     pad_m = (-m) % bm
-    if pad_m:
-        z = jnp.pad(z, (0, pad_m))
-        aq = jnp.pad(aq, (0, pad_m))
-        warm_y = jnp.pad(warm_y, (0, pad_m), constant_values=-1)
-    ok = (jnp.ones_like(rn_flat) if y_ok is None else jnp.asarray(y_ok))
+    col = lambda x, dt, fill=0: jnp.pad(x.astype(dt), (0, pad_m),
+                                        constant_values=fill)[:, None]
+    row = lambda x: jnp.asarray(x, jnp.float32)[None, :]
+    ok = jnp.ones_like(rn_flat) if y_ok is None else y_ok
     y_f, v_star, o_up, o_down, iters, infeas = _pallas(
-        z.astype(jnp.float32), aq.astype(jnp.float32),
-        warm_y.astype(jnp.int32),
-        rn_flat.astype(jnp.float32), pn_flat.astype(jnp.float32),
-        tier_flat.astype(jnp.float32), ok.astype(jnp.float32),
+        col(z, jnp.float32), col(aq, jnp.float32),
+        col(warm_y, jnp.int32, fill=-1),
+        row(rn_flat), row(pn_flat), row(tier_flat), row(ok),
         jnp.moveaxis(b2_flat, -1, 0).astype(jnp.float32),    # (K, F)
-        u_all.astype(jnp.float32), c1_flat.astype(jnp.float32),
+        jnp.moveaxis(u_all, -1, 0).astype(jnp.float32),      # (K, P)
+        row(c1_flat),
         margin=margin, num_versions=num_versions, max_iters=max_iters,
-        theta=theta, block_m=bm, interpret=not _on_tpu(),
+        theta=theta, block_m=bm, interpret=interpret,
     )
-    return (y_f[:m], v_star[:m], o_up[:m], o_down[:m], iters[:m],
-            infeas[:m] > 0)
+    return (y_f[:m, 0], v_star[:m, 0], o_up[:m, 0], o_down[:m, 0],
+            iters[:m, 0], infeas[:m, 0] > 0)

@@ -1,4 +1,5 @@
-"""jit'd public wrapper: dispatches Pallas on TPU, interpret/ref elsewhere."""
+"""jit'd public wrapper: dispatches the compiled Pallas kernel on TPU and
+the jnp ref elsewhere (``repro.kernels.dispatch``)."""
 from __future__ import annotations
 
 from functools import partial
@@ -7,17 +8,14 @@ import jax
 
 from repro.kernels.mamba_scan.kernel import selective_scan as _pallas
 from repro.kernels.mamba_scan.ref import selective_scan_ref as _ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+from repro.kernels.dispatch import pallas_interpret
 
 
 @partial(jax.jit, static_argnames=("block_t", "block_d", "force"))
 def selective_scan(x, dt, B, C, A, D, h0=None, *, block_t: int = 128,
                    block_d: int = 512, force: str = "auto"):
-    use_pallas = force == "pallas" or (force == "auto" and _on_tpu())
-    if use_pallas:
+    interpret = pallas_interpret(force)
+    if interpret is not None:
         return _pallas(x, dt, B, C, A, D, h0, block_t=block_t, block_d=block_d,
-                       interpret=not _on_tpu())
+                       interpret=interpret)
     return _ref(x, dt, B, C, A, D, h0)

@@ -1,4 +1,5 @@
-"""jit'd public wrapper: dispatches Pallas on TPU, interpret/ref elsewhere."""
+"""jit'd public wrapper: dispatches the compiled Pallas kernel on TPU and
+the jnp ref elsewhere (``repro.kernels.dispatch``)."""
 from __future__ import annotations
 
 from functools import partial
@@ -6,32 +7,25 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.dispatch import pallas_interpret
 from repro.kernels.temporal_gate.kernel import gate_cell as _pallas
 from repro.kernels.temporal_gate.ref import gate_cell_ref as _ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("block_b", "force"))
 def gate_cell(dx, h, vol, p, *, block_b: int = 256, force: str = "auto"):
     """Fused gating cell for a (B, d) stream batch -> (h_new, tau, g_mean).
 
-    ``force``: "auto" picks Pallas on TPU and the jnp ref elsewhere;
-    "pallas"/"ref" override (Pallas runs in interpret mode off-TPU).  The
+    ``force``: see :func:`repro.kernels.dispatch.pallas_interpret`.  The
     batch is padded up to a multiple of the kernel block so any B works.
     """
-    use_pallas = force == "pallas" or (force == "auto" and _on_tpu())
-    if not use_pallas:
+    interpret = pallas_interpret(force)
+    if interpret is None:
         return _ref(dx, h, vol, p)
     b = dx.shape[0]
     bb = min(block_b, b)
     pad = (-b) % bb
-    if pad:
-        dx = jnp.concatenate([dx, jnp.zeros((pad,) + dx.shape[1:], dx.dtype)])
-        h = jnp.concatenate([h, jnp.zeros((pad,) + h.shape[1:], h.dtype)])
-        vol = jnp.concatenate([vol, jnp.zeros((pad,), vol.dtype)])
-    h_new, tau, g_mean = _pallas(dx, h, vol, p, block_b=bb,
-                                 interpret=not _on_tpu())
-    return h_new[:b], tau[:b], g_mean[:b]
+    rows = lambda x: jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+    h_new, tau, g_mean = _pallas(rows(dx), rows(h), rows(vol[:, None]), p,
+                                 block_b=bb, interpret=interpret)
+    return h_new[:b], tau[:b, 0], g_mean[:b, 0]

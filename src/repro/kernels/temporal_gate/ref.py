@@ -19,14 +19,17 @@ def gate_cell_ref(dx, h, vol, p):
     form (tests lock the kernel/ref pair bit for bit).
     """
     m = h.shape[1]
+    # f32 matmuls on every backend (the TPU default is one bf16 pass): τ
+    # feeds threshold decisions, which must match the kernel's
+    mm = lambda a, b: jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST)
     w_x = jnp.concatenate([p["w_g"], p["w_r"], p["w_h"]], axis=1)   # (d, 3m)
     u_gr = jnp.concatenate([p["u_g"], p["u_r"]], axis=1)            # (m, 2m)
-    xw = dx @ w_x                                                   # (B, 3m)
-    hu = h @ u_gr                                                   # (B, 2m)
+    xw = mm(dx, w_x)                                                # (B, 3m)
+    hu = mm(h, u_gr)                                                # (B, 2m)
     g = jax.nn.sigmoid(xw[:, :m] + hu[:, :m] + p["b_g"]
                        + (p["alpha"] * vol)[:, None])
     r = jax.nn.sigmoid(xw[:, m:2 * m] + hu[:, m:] + p["b_r"])
-    cand = jnp.tanh(xw[:, 2 * m:] + (r * h) @ p["u_h"] + p["b_h"])
+    cand = jnp.tanh(xw[:, 2 * m:] + mm(r * h, p["u_h"]) + p["b_h"])
     h_new = (1.0 - g) * h + g * cand
-    tau = jax.nn.sigmoid(h_new @ p["w_o"] + p["b_o"])[:, 0]
+    tau = jax.nn.sigmoid(mm(h_new, p["w_o"]) + p["b_o"])[:, 0]
     return h_new, tau, g.mean(axis=-1)

@@ -1,21 +1,26 @@
+"""Multi-pod dry-run: lower and compile every (arch, shape, mesh) cell on
+virtual CPU devices.  The device count locks at JAX's first init, so the
+shell that starts it provides them:
+
+  XLA_FLAGS=--xla_force_host_platform_device_count=512 JAX_PLATFORMS=cpu \
+      PYTHONPATH=src python -m repro.launch.dryrun
+"""
+import argparse
+import json
 import os
-os.environ["XLA_FLAGS"] = os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count at first init).  Do not move them.
+import time
+import traceback
 
-import argparse  # noqa: E402
-import json      # noqa: E402
-import time      # noqa: E402
-import traceback # noqa: E402
+import jax
+import zstandard
 
-import jax       # noqa: E402
-import zstandard # noqa: E402
+from repro.configs import ARCH_IDS, get_config
+from repro.launch.hlo_cost import analyze
+from repro.launch.mesh import make_production_mesh
+from repro.launch.roofline import model_flops, roofline_terms
+from repro.launch.steps import SHAPES, applicable_shapes, input_specs, rules_for, step_for
 
-from repro.configs import ARCH_IDS, get_config                      # noqa: E402
-from repro.launch.hlo_cost import analyze                           # noqa: E402
-from repro.launch.mesh import make_production_mesh                  # noqa: E402
-from repro.launch.roofline import model_flops, roofline_terms       # noqa: E402
-from repro.launch.steps import SHAPES, applicable_shapes, input_specs, rules_for, step_for  # noqa: E402
+_DEVICES_NEEDED = 512   # the (2, 16, 16) multi-pod mesh
 
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str, *, overrides=None, tag=""):
@@ -107,6 +112,11 @@ def main():
     ap.add_argument("--mesh", default="both", choices=["single", "multi", "both"])
     ap.add_argument("--out", default="results/dryrun")
     args = ap.parse_args()
+    if jax.device_count() < _DEVICES_NEEDED:
+        raise SystemExit(
+            f"dry-run needs {_DEVICES_NEEDED} devices, found "
+            f"{jax.device_count()}: start it with XLA_FLAGS="
+            f"--xla_force_host_platform_device_count={_DEVICES_NEEDED}")
 
     archs = [args.arch] if args.arch else list(ARCH_IDS)
     meshes = {"single": [False], "multi": [True], "both": [False, True]}[args.mesh]

@@ -14,6 +14,7 @@ from typing import Dict, Optional
 
 import jax
 
+from repro.sharding.compat import make_mesh
 from repro.train.trainer import NodeFailure
 
 
@@ -61,7 +62,7 @@ def elastic_remesh(n_devices: Optional[int] = None, *, min_model: int = 1,
                 model = cand
                 break
     data = n // model
-    return jax.make_mesh((data, model), ("data", "model"), devices=devs[:n])
+    return make_mesh((data, model), ("data", "model"), devices=devs[:n])
 
 
 class ClusterSim:

@@ -759,6 +759,14 @@ class ServeSession:
             self.hedge)
         return mets
 
+    def lower_run(self, stream: Observation):
+        """The lowered program a plain (dense, churn- and finetune-free)
+        :meth:`run` executes on ``stream`` — for inspection: which kernels
+        it holds, its compile time and memory."""
+        self._check_obs(stream, rounds=True)
+        return _serve_run.lower(self.policy, self.state, stream, self.n_edge,
+                                self.n_cloud, self.hedge)
+
     def run_sharded(self, mesh, stream: Observation,
                     n_rounds: int | None = None, mesh_axis: str = "data",
                     hierarchical: bool | None = None):

@@ -14,6 +14,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 #: primitive-name fragments that imply cross-device traffic under shard_map
 COLLECTIVE_PRIMS = ("all_gather", "all_to_all", "psum", "pmax", "pmin",
@@ -27,9 +28,9 @@ def _sub_jaxprs(params):
     for val in params.values():
         vals = val if isinstance(val, (tuple, list)) else (val,)
         for v in vals:
-            if isinstance(v, jax.core.ClosedJaxpr):
+            if isinstance(v, ClosedJaxpr):
                 yield v.jaxpr
-            elif isinstance(v, jax.core.Jaxpr):
+            elif isinstance(v, Jaxpr):
                 yield v
 
 
@@ -42,7 +43,7 @@ def iter_collectives(jaxpr, _in_loop=False):
     body.  ``in_loop`` marks equations nested (at any depth) inside a
     ``scan``/``while`` body, i.e. executed every serving round.
     """
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name

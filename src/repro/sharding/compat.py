@@ -1,15 +1,30 @@
-"""jax-version-compatible ``shard_map`` + batch-padding helpers.
+"""Mesh construction, ``shard_map`` and batch-padding helpers.
 
-jax >= 0.5 exports ``shard_map`` at the top level with a ``check_vma``
-kwarg; older releases keep it under ``jax.experimental`` with ``check_rep``.
-Every shard_map user in the repo (pipeline parallelism, the sharded CCG
-sweep, the sharded ``serve_scan``, compressed collectives) goes through this
-shim, and every sharded entry point that rounds a task/stream batch up to
-the device count uses :func:`pad_leading`.
+Every mesh in the repo is built by :func:`make_mesh`, with Auto axes:
+``jax.make_mesh`` defaults to Explicit axes, under which eager slicing of a
+``shard_map`` output raises a sharding-type error.  Every shard_map user
+(pipeline parallelism, the sharded CCG sweep, the sharded serve scan,
+compressed collectives) imports :func:`shard_map` from here, and every
+sharded entry point that rounds a task/stream batch up to the device count
+uses :func:`pad_leading`.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType
+
+shard_map = jax.shard_map
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """A ``jax.sharding.Mesh`` of ``shape`` over ``axes`` with Auto axes.
+
+    ``devices`` defaults to ``jax.devices()``; pass a subset (survivor
+    meshes) or described devices (compile-only rehearsals)."""
+    shape, axes = tuple(shape), tuple(axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def pad_leading(x, pad: int, value=0, axis: int = 0):
@@ -25,18 +40,3 @@ def pad_leading(x, pad: int, value=0, axis: int = 0):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths, constant_values=value)
-
-try:  # jax >= 0.5
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-except ImportError:  # older jax: experimental namespace, check_rep kwarg
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
-        )

@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from repro.checkpoint.manager import CheckpointManager, restore, save
+from repro.sharding.compat import make_mesh
 
 TMP = "results/_test_ckpt"
 
@@ -62,7 +63,7 @@ def test_elastic_reshard_restore():
 
     tree = _tree(2)
     save(os.path.join(TMP, "y"), tree)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = jax.tree_util.tree_map(lambda _: NamedSharding(mesh, P()), tree)
     out, _ = restore(os.path.join(TMP, "y"), tree, shardings=sh)
     for leaf in jax.tree_util.tree_leaves(out):

@@ -234,6 +234,7 @@ def test_sharded_churn_matches_dense():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import dataclasses
         import jax, jax.numpy as jnp
+        from repro.sharding.compat import make_mesh
         import numpy as np
         from repro.core.cost_model import SystemConfig
         from repro.serving.policy import make_policy
@@ -253,7 +254,7 @@ def test_sharded_churn_matches_dense():
         acfg = AdmissionConfig(init_alive=m // 2)
         pol = make_policy("rdap", sys_)
         dense = ServeSession(pol, m, sim=simc, admission=acfg).run(stream)
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         sess = ServeSession(pol, m, sim=simc, admission=acfg)
         shard = sess.run_sharded(mesh, stream)
         assert set(dense) == set(shard)

@@ -15,17 +15,15 @@ SCRIPT = textwrap.dedent(
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax
+    from repro.sharding.compat import make_mesh
     import jax.numpy as jnp
     import numpy as np
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from repro.sharding.compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.train.compression import compressed_allreduce
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = make_mesh((8,), ("data",))
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 64)) * 0.3
 
     def body(xs):

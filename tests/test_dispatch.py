@@ -12,8 +12,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import get_smoke_config
+from repro.configs import get_config, get_smoke_config
 from repro.core.cost_model import SystemConfig
+from repro.launch.serve import serve, tier_configs
 from repro.serving.dispatch import (
     DispatchExecutor,
     PoolExecutor,
@@ -293,3 +294,41 @@ def test_session_feedback_changes_routing_decisions(pools):
     # routing itself stays consistent for the originally alive streams
     np.testing.assert_array_equal(np.asarray(out_fb["alive"]),
                                   np.asarray(out_blind["alive"]))
+
+
+# ---------------------------------------------------------------------------
+# The launcher's serving loop
+# ---------------------------------------------------------------------------
+def test_launcher_serve_dispatches_every_routed_segment(pools):
+    """``launch.serve.serve`` (behind both the launcher and the chip smoke)
+    routes each round with ``route_many`` and serves every routed segment of
+    the round's last step once, sized by its own fidelity."""
+    decode, streams, rounds = 2, 6, 2
+    out = serve(pools, streams=streams, rounds=rounds, segments_per_round=2,
+                seed=3, requirement="fluctuating", decode_tokens=decode)
+    assert len(out["rounds"]) == rounds
+    for rnd in out["rounds"]:
+        route = np.asarray(rnd["sol"]["route"])
+        r = np.asarray(rnd["sol"]["r"])
+        assert route.shape == (streams,)
+        for tier in pools:
+            lanes = route == tier
+            st = rnd["served"].get(tier, {"requests": 0, "tokens": 0})
+            assert st["requests"] == int(lanes.sum())
+            assert st["tokens"] == int((16 * (1 + r[lanes]) + decode).sum())
+    assert set(out["pools"]) == {p.name for p in pools.values()}
+
+
+def test_tier_configs_full_preset_cuts_only_cloud_depth():
+    edge, cloud = tier_configs("qwen1.5-0.5b", "qwen3-8b", "full", 5)
+    assert edge == get_config("qwen1.5-0.5b")
+    whole = get_config("qwen3-8b")
+    assert cloud == dataclasses.replace(whole, num_layers=5)
+    assert tier_configs("qwen1.5-0.5b", "qwen3-8b", "full")[1] == whole
+    assert tier_configs("qwen1.5-0.5b", "qwen3-8b") == (
+        get_smoke_config("qwen1.5-0.5b"), get_smoke_config("qwen3-8b"))
+    for bad in (0, whole.num_layers + 1):
+        with pytest.raises(ValueError, match="cloud_layers"):
+            tier_configs("qwen1.5-0.5b", "qwen3-8b", "full", bad)
+    with pytest.raises(ValueError, match="preset"):
+        tier_configs("qwen1.5-0.5b", "qwen3-8b", "medium")

@@ -1,7 +1,8 @@
 """Validate collected multi-pod dry-run artifacts (skips if not yet run).
 
 The dry-run itself needs 512 fake devices and must run as its own process:
-  PYTHONPATH=src python -m repro.launch.dryrun
+  XLA_FLAGS=--xla_force_host_platform_device_count=512 JAX_PLATFORMS=cpu \
+      PYTHONPATH=src python -m repro.launch.dryrun
 """
 import glob
 import json

@@ -21,6 +21,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.models.params import init_params
 from repro.serving.scan import run_scan
 from repro.serving.simulator import SimConfig, Simulator
+from repro.sharding.compat import make_mesh
 
 SYS = SystemConfig()
 PROB = RobustProblem.build(SYS)
@@ -83,7 +84,7 @@ def test_gate_step_batch_pallas_interpret_parity():
     st_pal = init_batch_state(cfg, 4)
     for t in range(6):
         st_ref, (tau_r, _) = gate_step_batch(cfg, p, st_ref, dxs[t], force="ref")
-        st_pal, (tau_p, _) = gate_step_batch(cfg, p, st_pal, dxs[t], force="pallas")
+        st_pal, (tau_p, _) = gate_step_batch(cfg, p, st_pal, dxs[t], force="interpret")
         np.testing.assert_allclose(np.asarray(tau_p), np.asarray(tau_r), atol=1e-5)
     np.testing.assert_allclose(np.asarray(st_pal.h), np.asarray(st_ref.h), atol=1e-5)
 
@@ -97,7 +98,7 @@ def test_gate_cell_pads_odd_batches():
     dx = jax.random.normal(jax.random.PRNGKey(1), (b, cfg.d_feature))
     h = jax.random.normal(jax.random.PRNGKey(2), (b, cfg.d_hidden)) * 0.1
     vol = jnp.abs(jax.random.normal(jax.random.PRNGKey(3), (b,)))
-    got = gate_cell(dx, h, vol, p, block_b=4, force="pallas")
+    got = gate_cell(dx, h, vol, p, block_b=4, force="interpret")
     want = gate_cell(dx, h, vol, p, force="ref")
     for g, w in zip(got, want):
         assert g.shape == w.shape
@@ -139,13 +140,14 @@ def test_solve_ccg_sharded_multidevice():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax
+        from repro.sharding.compat import make_mesh
         import jax.numpy as jnp
         import numpy as np
         from repro.core.cost_model import SystemConfig
         from repro.core.robust import RobustProblem, solve_ccg, solve_ccg_sharded
 
         prob = RobustProblem.build(SystemConfig())
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         rng = np.random.default_rng(42)
         for m in (13, 16):  # 13: padding path; 16: exact split
             z = jnp.asarray(rng.uniform(0, 1, m), jnp.float32)
@@ -347,7 +349,7 @@ def test_unrolled_ccg_slab_master_paths_identical():
     aq = jnp.asarray(rng.uniform(0.5, 0.75, 19), jnp.float32)
     auto = solve_ccg(PROB, z, aq)
     _assert_ccg_identical(auto, solve_ccg(PROB, z, aq, force="ref"), "ref")
-    _assert_ccg_identical(auto, solve_ccg(PROB, z, aq, force="pallas"), "pallas")
+    _assert_ccg_identical(auto, solve_ccg(PROB, z, aq, force="interpret"), "interpret")
 
 
 # ---------------------------------------------------------------------------
@@ -362,7 +364,7 @@ def test_serve_scan_accepts_host_mesh():
     rng = np.random.default_rng(21)
     gcfg = GateConfig(d_feature=feature_dim())
     gparams = init_params(gate_specs(gcfg), jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((jax.device_count(),), ("data",))
+    mesh = make_mesh((jax.device_count(),), ("data",))
     dx = jnp.asarray(rng.normal(size=(r, m, feature_dim())), jnp.float32)
     z = jnp.asarray(rng.uniform(0, 1, (r, m)), jnp.float32)
     aq = jnp.asarray(rng.uniform(0.5, 0.7, (r, m)), jnp.float32)
@@ -396,6 +398,7 @@ def test_serve_scan_sharded_multidevice():
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import jax
+        from repro.sharding.compat import make_mesh
         import jax.numpy as jnp
         import numpy as np
         from repro.core.cost_model import SystemConfig
@@ -409,7 +412,7 @@ def test_serve_scan_sharded_multidevice():
         prob = RobustProblem.build(SystemConfig())
         gcfg = GateConfig(d_feature=feature_dim())
         gp = init_params(gate_specs(gcfg), jax.random.PRNGKey(0))
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         for m in (13, 16):  # 13: padding path; 16: exact split
             rng = np.random.default_rng(m)
             r = 4

@@ -120,14 +120,18 @@ def test_resync_cadence_one_matches_looped_oracle():
 
     states = [init_state(cfg) for _ in range(m)]
     st = init_batch_state(cfg, m)
+    # the fresh reduction is compiled like the in-step resync: XLA contracts
+    # the Σx² square-and-add into FMAs there, which eager op-by-op
+    # evaluation does not
+    exact = jax.jit(lambda b: (b.sum(axis=1), jnp.square(b).sum(axis=1)))
     for t in range(steps):
         st, (tau, _) = gate_step_batch(cfg, p, st, dxs[t])
         # every step: the incremental sums ARE the exact buffer reduction
-        np.testing.assert_array_equal(
-            np.asarray(st.var_sum), np.asarray(st.var_buf.sum(axis=1)))
-        np.testing.assert_array_equal(
-            np.asarray(st.var_sumsq),
-            np.asarray(jnp.square(st.var_buf).sum(axis=1)))
+        want_sum, want_sumsq = exact(st.var_buf)
+        np.testing.assert_array_equal(np.asarray(st.var_sum),
+                                      np.asarray(want_sum))
+        np.testing.assert_array_equal(np.asarray(st.var_sumsq),
+                                      np.asarray(want_sumsq))
         for i in range(m):
             states[i], (tau_ref, _) = gate_step(cfg, p, states[i], dxs[t, i])
             np.testing.assert_allclose(

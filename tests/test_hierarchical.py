@@ -27,6 +27,7 @@ from repro.serving.policy import make_policy
 from repro.serving.session import ServeSession, _serve_run_sharded
 from repro.serving.simulator import SimConfig, Simulator
 from repro.sharding.audit import collective_footprint
+from repro.sharding.compat import make_mesh
 
 SYS = SystemConfig()
 PROB = RobustProblem.build(SYS)
@@ -188,7 +189,7 @@ def test_one_device_hierarchical_bit_identical(name):
     simc, stream = _serve_stream(m=12, r=5, seed=3)
     pol = make_policy(name, SYS)
     dense = ServeSession(pol, 12, sim=simc).run(stream)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     hier = ServeSession(pol, 12, sim=simc, hierarchical=True).run_sharded(
         mesh, stream)
     assert set(dense) == set(hier)
@@ -206,7 +207,7 @@ def test_round_body_collectives_are_device_count_sized():
     m = 24
     simc, stream = _serve_stream(m=m, r=3)
     pol = make_policy("r2evid", SYS)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     state = pol.init(m)
 
     def footprint(hier):
@@ -230,7 +231,7 @@ def test_hierarchical_rejects_hedge():
     simc, stream = _serve_stream(m=8, r=2, bw_scale=None)
     sess = ServeSession(make_policy("rdap", SYS), 8, sim=simc,
                         hedge=(0.9, 0.05), hierarchical=True)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     with pytest.raises(ValueError, match="hedge"):
         sess.run_sharded(mesh, stream)
 
@@ -257,6 +258,7 @@ def test_eight_device_decision_parity_and_footprint():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import dataclasses
         import jax, jax.numpy as jnp
+        from repro.sharding.compat import make_mesh
         import numpy as np
         from repro.core.cost_model import SystemConfig
         from repro.serving.policy import make_policy
@@ -273,7 +275,7 @@ def test_eight_device_decision_parity_and_footprint():
         pol = make_policy("r2evid", sys_)
         kw = dict(sim=simc, n_edge=16, n_cloud=8)
         dense = ServeSession(pol, m, **kw).run(stream)
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = make_mesh((8,), ("data",))
         gath = ServeSession(pol, m, **kw).run_sharded(mesh, stream)
         hier = ServeSession(pol, m, **kw).run_sharded(
             mesh, stream, hierarchical=True)
@@ -325,6 +327,7 @@ def test_uneven_m_churn_outage_collapse_parity():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import dataclasses
         import jax, jax.numpy as jnp
+        from repro.sharding.compat import make_mesh
         import numpy as np
         from repro.core.cost_model import SystemConfig
         from repro.serving.policy import make_policy
@@ -348,7 +351,7 @@ def test_uneven_m_churn_outage_collapse_parity():
         pol = make_policy("r2evid", sys_)
         acfg = AdmissionConfig(init_alive=m // 2)
         dense = ServeSession(pol, m, sim=simc, admission=acfg).run(stream)
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         gath = ServeSession(pol, m, sim=simc,
                             admission=acfg).run_sharded(mesh, stream)
         hier = ServeSession(pol, m, sim=simc, admission=acfg).run_sharded(
@@ -385,6 +388,7 @@ def test_sniper_sharded_replicated_profile_parity():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
         import dataclasses
         import jax, jax.numpy as jnp
+        from repro.sharding.compat import make_mesh
         import numpy as np
         from repro.core.cost_model import SystemConfig
         from repro.serving.policy import make_policy
@@ -398,7 +402,7 @@ def test_sniper_sharded_replicated_profile_parity():
         stream = Simulator(sys_, simc).sample_stream(r)
         pol = make_policy("sniper", sys_)
         dense = ServeSession(pol, m, sim=simc).run(stream)
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = make_mesh((4,), ("data",))
         gath = ServeSession(pol, m, sim=simc).run_sharded(mesh, stream)
         hier = ServeSession(pol, m, sim=simc).run_sharded(
             mesh, stream, hierarchical=True)

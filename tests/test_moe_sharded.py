@@ -14,6 +14,7 @@ SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax
+    from repro.sharding.compat import make_mesh
     import jax.numpy as jnp
     import numpy as np
 
@@ -28,7 +29,7 @@ SCRIPT = textwrap.dedent(
     p = init_params(moe_specs(cfg), jax.random.PRNGKey(0), dtype_override=jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (8, 16, cfg.d_model), jnp.float32)
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     rules = make_rules(mesh, "train")
     ctx_sharded = Ctx(cfg=cfg, rules=rules)
     with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else mesh:

@@ -14,6 +14,7 @@ SCRIPT = textwrap.dedent(
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax
+    from repro.sharding.compat import make_mesh
     import jax.numpy as jnp
     import numpy as np
 
@@ -41,7 +42,7 @@ SCRIPT = textwrap.dedent(
         out, _ = jax.lax.scan(body, x, (ws, bs))
         return out
 
-    mesh = jax.make_mesh((4,), ("stage",))
+    mesh = make_mesh((4,), ("stage",))
     stage_params = split_stages((w, b), S)
     fn = pipeline(stage_fn, mesh, axis="stage")
     out = jax.jit(fn)(stage_params, xs)

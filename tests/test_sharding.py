@@ -9,14 +9,15 @@ from hypothesis import given, settings
 from jax.sharding import PartitionSpec as P
 
 from repro.sharding.rules import SERVE_BASE, TRAIN_BASE, make_rules
+from repro.sharding.compat import make_mesh
 
 
 def _mesh(multi=False):
     # tiny host mesh stands in; axis names are what matter for specs
     n = len(jax.devices())
     if multi:
-        return jax.make_mesh((1, 1, n), ("pod", "data", "model"))
-    return jax.make_mesh((1, n), ("data", "model"))
+        return make_mesh((1, 1, n), ("pod", "data", "model"))
+    return make_mesh((1, n), ("data", "model"))
 
 
 def test_rule_tables_cover_all_logical_axes():
