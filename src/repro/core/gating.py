@@ -115,6 +115,7 @@ def init_batch_state(cfg: GateConfig, n_streams: int) -> GateBatchState:
     )
 
 
+@jax.named_scope("r2e.gate")
 def gate_step_batch(cfg: GateConfig, p, state: GateBatchState, dx, *,
                     force: str = "auto"):
     """One fused recurrence step for all streams. dx: (M, d).

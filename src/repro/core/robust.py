@@ -308,6 +308,7 @@ def solve_ccg(prob: RobustProblem, difficulty, acc_req, max_iters: int = 8,
 
 
 @partial(jax.jit, static_argnames=("max_iters", "theta", "force"))
+@jax.named_scope("r2e.ccg")
 def solve_ccg_fused(prob: RobustProblem, difficulty, acc_req,
                     max_iters: int = 8, theta: float = 1e-4, warm_y=None,
                     force: str = "auto", tier_ok=None):

@@ -64,6 +64,7 @@ def temporal_flip_allowed(taus, prev_tau, rcfg: RouterConfig):
     return (jnp.abs(taus - prev_tau) * rcfg.delta1 + rcfg.delta0) >= 1.0
 
 
+@jax.named_scope("r2e.consistency")
 def apply_temporal_consistency(route, prev_route, taus, prev_tau, rcfg: RouterConfig):
     """Suppress forbidden flips; ``prev_route < 0`` means no history (allowed)."""
     allowed = temporal_flip_allowed(taus, prev_tau, rcfg)
@@ -71,6 +72,7 @@ def apply_temporal_consistency(route, prev_route, taus, prev_tau, rcfg: RouterCo
     return jnp.where(flip & ~allowed & (prev_route >= 0), prev_route, route)
 
 
+@jax.named_scope("r2e.consistency")
 def clamp_route_available(route, tier_ok):
     """Force routes off outaged tiers.  ``tier_ok``: (..., 2) availability
     (0 = edge, 1 = cloud; <= 0 means down).  Availability overrides every
@@ -84,6 +86,7 @@ def clamp_route_available(route, tier_ok):
 # ---------------------------------------------------------------------------
 # Stage 1: adaptive edge-cloud configuration (Alg. 1)
 # ---------------------------------------------------------------------------
+@jax.named_scope("r2e.stage1")
 def stage1_configure(sys_or_lat, taus, difficulty, acc_req, prev_route, prev_tau,
                      rcfg: RouterConfig = RouterConfig(), tier_ok=None):
     """Vectorized Alg. 1.  All inputs (M,).  Returns route, r_idx warm starts.
@@ -116,6 +119,7 @@ def stage1_configure(sys_or_lat, taus, difficulty, acc_req, prev_route, prev_tau
 # ---------------------------------------------------------------------------
 # C6 bandwidth repair
 # ---------------------------------------------------------------------------
+@jax.named_scope("r2e.repair")
 def enforce_bandwidth(sys_or_lat, sol, difficulty, acc_req, total_budget=None,
                       rounds: int = 8, force: str = "auto", task_mask=None):
     """Demote (r, p) of over-budget tasks with the largest bandwidth draw that
@@ -300,7 +304,8 @@ def _two_stage_select(
         tier_ok=tier_ok
     )
     # Stage-1 picks (route, r) at max fps — seed CCG with that configuration
-    warm_y = lat.flatten_index(warm_route, warm_r, lat.sys.n_fps - 1)
+    with jax.named_scope("r2e.stage1"):
+        warm_y = lat.flatten_index(warm_route, warm_r, lat.sys.n_fps - 1)
     sol = solve_ccg_fused(prob, difficulty, acc_req,
                           warm_y=warm_y.astype(jnp.int32), force=force,
                           tier_ok=tier_ok)

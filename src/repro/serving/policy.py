@@ -627,6 +627,7 @@ class R2EVidPolicy(Policy):
                                  prev_tau=jnp.asarray(taus, jnp.float32))
         return state, sol
 
+    @jax.named_scope("r2e.repair")
     def repair(self, sol, z, aq, tier_ok=None, bw_scale=None, task_mask=None):
         if not self._full:
             return sol

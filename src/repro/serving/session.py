@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.gating import gate_step_batch
+from repro.runtime.spans import span
 from repro.serving.policy import Observation, Policy, capacity_budget
 from repro.serving.simulator import SimConfig, realize_rounds
 
@@ -676,7 +677,8 @@ class ServeSession:
     # -- decide-only fast paths (RouterEngine / launch loop) ---------------
     def route(self, obs: Observation):
         """Route one segment batch (no realization).  Returns the solution."""
-        self.state, sol = _decide_step(self.policy, self.state, obs)
+        with span("r2e.launch"):
+            self.state, sol = _decide_step(self.policy, self.state, obs)
         return sol
 
     def route_many(self, dx_seq, difficulty, acc_req):
@@ -706,13 +708,14 @@ class ServeSession:
         """One serving round.  With ``bw_mult``/``u`` on the observation the
         round is realized and (sol+metrics) returned; without them this is
         ``route``."""
-        self._check_obs(obs, rounds=False)
-        if obs.u is None or obs.bw_mult is None:
-            return self.route(obs)
-        self.state, out = _serve_step(
-            self.policy, self.state, obs, self.n_edge, self.n_cloud,
-            self.hedge)
-        return out
+        with span("r2e.step"):
+            self._check_obs(obs, rounds=False)
+            if obs.u is None or obs.bw_mult is None:
+                return self.route(obs)
+            self.state, out = _serve_step(
+                self.policy, self.state, obs, self.n_edge, self.n_cloud,
+                self.hedge)
+            return out
 
     def run(self, stream: Observation, n_rounds: int | None = None,
             mesh=None, mesh_axis: str | None = None):

@@ -120,3 +120,34 @@ def test_routing_kernel_compiles_for_v5e(name, m, one_chip,
     fn, args = _kernel_call(name, m, spec)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+def test_decide_program_for_v5e_keeps_its_stage_scopes(one_chip,
+                                                       no_persistent_cache):
+    """The whole decide program at fleet size, as the chip compiles it: the
+    three routing kernels it runs, and every operation that carries the
+    program's metadata under one of the five stage scopes (what a device
+    trace of the chip attributes its time by)."""
+    import dataclasses
+
+    from test_spans import unscoped_program_ops
+
+    from repro.serving.policy import Observation, make_policy
+    from repro.serving.session import _decide_step
+
+    m, d = 4096, feature_dim()
+    gcfg = GateConfig(d_feature=d)
+    policy = make_policy("r2evid", SystemConfig(), gate_cfg=gcfg,
+                         gate_params=init_params(gate_specs(gcfg),
+                                                 jax.random.PRNGKey(0)))
+    policy = dataclasses.replace(policy, force="pallas")
+    obs = Observation(z=jnp.zeros((m,)), aq=jnp.zeros((m,)),
+                      dx=jnp.zeros((m, d)), bw_scale=jnp.float32(0.3))
+    abstract = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.asarray(x).dtype,
+                                       sharding=one_chip), tree)
+    text = _decide_step.lower(abstract(policy), abstract(policy.init(m)),
+                              abstract(obs)).compile().as_text()
+    for kernel in ("%gate_cell", "%ccg_solve", "%c6_tail"):
+        assert kernel in text, kernel
+    assert unscoped_program_ops(text) == []
