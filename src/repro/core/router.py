@@ -139,11 +139,14 @@ def enforce_bandwidth(sys_or_lat, sol, difficulty, acc_req, total_budget=None,
     accuracies, reclaimable gain — is the fused ``c6_tail`` kernel on the
     hoisted route-indexed (M, N·Z) bandwidth panel (bit-identical to the
     historical ``take_along_axis`` + ``accuracy_at`` body); only the global
-    argsort/prefix choice stays here.  Rounds are self-terminating: once a
-    round demotes nothing (or the budget holds), every later round is a
-    deterministic no-op on the same (r, p), so the scan skips the tail work
-    under a ``lax.cond`` and emits the bit-identical ``excess + budget``
-    history entry.
+    sort/prefix choice stays here.  The scan body holds no data-dependent
+    gather or scatter over the M tasks (a TPU runs each as a slow op in
+    every pass): the draw is a one-hot fold, the sorted gains come out of
+    the sort itself, and the demoted set is a threshold on the sort key.
+    Rounds are self-terminating: once a round demotes nothing (or the
+    budget holds), every later round is a deterministic no-op on the same
+    (r, p), so the scan skips the tail work under a ``lax.cond`` and emits
+    the bit-identical ``excess + budget`` history entry.
     """
     lat = _as_lattice(sys_or_lat)
     sys = lat.sys
@@ -156,8 +159,13 @@ def enforce_bandwidth(sys_or_lat, sol, difficulty, acc_req, total_budget=None,
     # the scan body once, flat (r·Z + p)-indexed inside
     bw_panel = jnp.moveaxis(lat.bw, -1, 0)[sol["route"]]   # (M, N, Z)
     bw_panel = bw_panel.reshape(bw_panel.shape[0], -1)     # (M, N·Z)
-    _take_bw = lambda r, p: jnp.take_along_axis(
-        bw_panel, (r * nz + p)[:, None], axis=1)[:, 0]
+    # per-pass draw as a one-hot fold over the panel's lanes (as c6_tail
+    # folds its own): one nonzero term, so bit-identical to the lane gather.
+    # The barrier keeps XLA from merging this lane sum into the draw's total
+    # (a reduce of a reduce), which would re-associate the budget sum
+    lane = jax.lax.broadcasted_iota(jnp.int32, bw_panel.shape, 1)
+    _take_bw = lambda r, p: jax.lax.optimization_barrier(jnp.where(
+        lane == (r * nz + p)[:, None], bw_panel, 0.0).sum(axis=1))
     if task_mask is None:
         take_bw = _take_bw
     else:
@@ -166,6 +174,7 @@ def enforce_bandwidth(sys_or_lat, sol, difficulty, acc_req, total_budget=None,
     acc_thr = jnp.asarray(acc_req, jnp.float32) + sys.acc_margin_robust
     rn = res_norm(sys)
     pn = fps_norm(sys)
+    iota = jax.lax.iota(jnp.int32, m)
 
     def round_fn(state, _):
         r, p, active = state
@@ -182,17 +191,33 @@ def enforce_bandwidth(sys_or_lat, sol, difficulty, acc_req, total_budget=None,
             p_dn = jnp.maximum(p - 1, 0)
             r_dn = jnp.maximum(r - 1, 0)
             # top-k demotion: in descending-gain order, demote tasks while the
-            # cumulative reclaimed bandwidth is still short of the excess
-            order = jnp.argsort(-gain)
-            gain_sorted = gain[order]
+            # cumulative reclaimed bandwidth is still short of the excess.
+            # One stable sort keyed on -gain carries the index along: the
+            # order (ties by index) of jnp.argsort(-gain), and the sorted
+            # gains as its negated keys, with no gather to fetch them back
+            neg_sorted, order = jax.lax.sort(
+                (-gain, iota), num_keys=1, is_stable=True)
+            gain_sorted = -neg_sorted
             cum_before = jnp.concatenate(
                 [jnp.zeros((1,), gain.dtype), jnp.cumsum(gain_sorted)[:-1]]
             )
             demote_sorted = (cum_before < excess) & (gain_sorted > 0)
-            demote = jnp.zeros((m,), bool).at[order].set(demote_sorted)
+            # demote_sorted is a prefix of the sorted order: the positive
+            # gains lead it (descending), and over them cum_before only
+            # grows, so `cum_before < excess` fails at most once and for
+            # good.  Its n members are then the tasks that sort no later
+            # than the last one, (g_last, i_last): a threshold test instead
+            # of a scatter back to task order.  g_last > 0, so no
+            # signed-zero tie arises.
+            n = demote_sorted.sum(dtype=jnp.int32)
+            last = jnp.maximum(n - 1, 0)
+            g_last = jax.lax.dynamic_index_in_dim(gain_sorted, last, keepdims=False)
+            i_last = jax.lax.dynamic_index_in_dim(order, last, keepdims=False)
+            demote = (n > 0) & ((gain > g_last)
+                                | ((gain == g_last) & (iota <= i_last)))
             return (jnp.where(demote & ~can_p, r_dn, r),
                     jnp.where(demote & can_p, p_dn, p),
-                    demote.any())
+                    n > 0)
 
         def skip_round(rp):
             r, p = rp

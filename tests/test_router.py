@@ -3,6 +3,7 @@ consistency constraint, the streaming engine, and vectorized realization."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.cost_model import SystemConfig, accuracy_table, cost_tables
 from repro.core.features import feature_dim
@@ -371,6 +372,178 @@ def test_enforce_bandwidth_table_free_matches_table_golden():
                 err_msg=f"frac={frac}:{k}")
         np.testing.assert_array_equal(
             np.asarray(got_hist), np.asarray(want_hist), err_msg=f"frac={frac}")
+
+
+def _enforce_bandwidth_gather_golden(sys_or_lat, sol, difficulty, acc_req,
+                                     total_budget=None, rounds: int = 8,
+                                     force: str = "auto", task_mask=None):
+    """The C6 repair with the per-pass draw gather, ``gain[order]`` and the
+    scatter back to task order — kept verbatim as the parity golden of the
+    gather-free loop; it also emits each pass's ``demote_sorted`` (all False
+    in a skipped pass) so the test can check that it is a prefix."""
+    from repro.core.cost_model import fps_norm, res_norm
+    from repro.core.router import _as_lattice
+    from repro.kernels.c6_tail.ops import c6_tail
+
+    lat = _as_lattice(sys_or_lat)
+    sys = lat.sys
+    budget = sys.total_bw_mbps if total_budget is None else total_budget
+
+    m = sol["r"].shape[0]
+    nz = sys.n_fps
+    bw_panel = jnp.moveaxis(lat.bw, -1, 0)[sol["route"]]   # (M, N, Z)
+    bw_panel = bw_panel.reshape(bw_panel.shape[0], -1)     # (M, N·Z)
+    _take_bw = lambda r, p: jnp.take_along_axis(
+        bw_panel, (r * nz + p)[:, None], axis=1)[:, 0]
+    if task_mask is None:
+        take_bw = _take_bw
+    else:
+        take_bw = lambda r, p: jnp.where(task_mask, _take_bw(r, p), 0.0)
+    z = jnp.asarray(difficulty, jnp.float32)
+    acc_thr = jnp.asarray(acc_req, jnp.float32) + sys.acc_margin_robust
+    rn = res_norm(sys)
+    pn = fps_norm(sys)
+
+    def round_fn(state, _):
+        r, p, active = state
+        bw = take_bw(r, p)
+        excess = bw.sum() - budget
+
+        def demote_round(rp):
+            r, p = rp
+            _, gain, can_p = c6_tail(
+                bw_panel, r, p, sol["v"], sol["route"], z, acc_thr, rn, pn,
+                n_fps=nz, force=force)
+            if task_mask is not None:
+                gain = jnp.where(task_mask, gain, 0.0)
+            p_dn = jnp.maximum(p - 1, 0)
+            r_dn = jnp.maximum(r - 1, 0)
+            order = jnp.argsort(-gain)
+            gain_sorted = gain[order]
+            cum_before = jnp.concatenate(
+                [jnp.zeros((1,), gain.dtype), jnp.cumsum(gain_sorted)[:-1]]
+            )
+            demote_sorted = (cum_before < excess) & (gain_sorted > 0)
+            demote = jnp.zeros((m,), bool).at[order].set(demote_sorted)
+            return (jnp.where(demote & ~can_p, r_dn, r),
+                    jnp.where(demote & can_p, p_dn, p),
+                    demote.any(), demote_sorted)
+
+        def skip_round(rp):
+            r, p = rp
+            return r, p, jnp.asarray(False), jnp.zeros((m,), bool)
+
+        r, p, progressed, demote_sorted = jax.lax.cond(
+            active & (excess > 0), demote_round, skip_round, (r, p))
+        return (r, p, progressed), (excess + budget, demote_sorted)
+
+    (r, p, _), (bw_hist, demote_sorted) = jax.lax.scan(
+        round_fn, (sol["r"], sol["p"], jnp.asarray(True)), None, length=rounds)
+    return dict(sol, r=r, p=p), bw_hist, demote_sorted
+
+
+def _fleet4096_lattice():
+    """The lattice of the benchmark's ``fleet4096`` deployment."""
+    import json
+    from pathlib import Path
+
+    cfg = json.loads((Path(__file__).resolve().parents[1] / "bench" / "configs"
+                      / "fleet4096.json").read_text())
+    return DecisionLattice.build(SystemConfig(**{
+        k: tuple(v) if isinstance(v, list) else v
+        for k, v in cfg["deployment"].items()}))
+
+
+def _random_fleet_repair_case(lat, m: int, masked: bool, seed: int):
+    """Random (route, r, p, v) over M tasks — gains are differences in a
+    25-entry table, so exact gain ties are frequent — with the starting
+    draw the repair sees (dead lanes draw nothing)."""
+    sys = lat.sys
+    rng = np.random.default_rng(seed)
+    sol = {
+        "route": jnp.asarray(rng.integers(0, 2, m), jnp.int32),
+        "r": jnp.asarray(rng.integers(0, sys.n_res, m), jnp.int32),
+        "p": jnp.asarray(rng.integers(0, sys.n_fps, m), jnp.int32),
+        "v": jnp.asarray(rng.integers(0, sys.num_versions, m), jnp.int32),
+    }
+    z = jnp.asarray(rng.uniform(0.02, 1.0, m), jnp.float32)
+    aq = jnp.asarray(rng.uniform(0.5, 0.8, m), jnp.float32)
+    mask = jnp.asarray(rng.uniform(size=m) < 0.8) if masked else None
+    draw = np.asarray(lat.solution_bandwidth(sol), np.float64)
+    if masked:
+        draw = draw * np.asarray(mask)
+    return sol, z, aq, mask, float(draw.sum())
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["dense", "masked"])
+@pytest.mark.parametrize("frac", [2.0, 0.9, 0.6, 0.3])
+def test_enforce_bandwidth_gather_free_matches_gather_golden(frac, masked):
+    """The gather-free repair loop (one-hot draw, gains from the sort's own
+    keys, threshold demotion mask) == the gather/scatter golden, bit for bit
+    in (r, p) and the bandwidth history, at fleet size with and without a
+    task mask; and every active pass demotes a prefix of the sorted order,
+    which the threshold form relies on."""
+    lat = _fleet4096_lattice()
+    sol, z, aq, mask, start_bw = _random_fleet_repair_case(
+        lat, 4096, masked, seed=int(frac * 10) + 100 * masked)
+    budget = jnp.float32(frac * start_bw)
+    got, got_hist = jax.jit(lambda s, z, aq, b, mk: enforce_bandwidth(
+        lat, s, z, aq, total_budget=b, force="ref", task_mask=mk))(
+            sol, z, aq, budget, mask)
+    want, want_hist, demote_sorted = jax.jit(
+        lambda s, z, aq, b, mk: _enforce_bandwidth_gather_golden(
+            lat, s, z, aq, total_budget=b, force="ref", task_mask=mk))(
+                sol, z, aq, budget, mask)
+    for k in ("r", "p"):
+        np.testing.assert_array_equal(np.asarray(got[k]), np.asarray(want[k]),
+                                      err_msg=k)
+    np.testing.assert_array_equal(np.asarray(got_hist), np.asarray(want_hist))
+    demote_sorted = np.asarray(demote_sorted)
+    n = demote_sorted.sum(axis=1)
+    for k, row in enumerate(demote_sorted):
+        assert row[:n[k]].all(), f"pass {k}: demoted set is not a prefix"
+    if frac < 1.0:
+        assert n.sum() > 0
+    else:
+        assert n.sum() == 0
+
+
+def test_enforce_bandwidth_loop_holds_no_gather_or_scatter():
+    """Structural guard: the lowered repair at fleet size holds no scatter,
+    and its only gather (outside the ``c6_tail`` call, whose CPU oracle
+    gathers and whose TPU kernel one-hot-folds) is the hoisted route-panel
+    gather before the loop.  A gather put back into the scan body would pass
+    every parity test; it fails here."""
+    import re
+
+    lat = _fleet4096_lattice()
+    m = 4096
+    i32 = jax.ShapeDtypeStruct((m,), jnp.int32)
+    f32 = jax.ShapeDtypeStruct((m,), jnp.float32)
+    for masked in (False, True):
+        mask = jax.ShapeDtypeStruct((m,), jnp.bool_) if masked else None
+        text = jax.jit(lambda s, z, aq, b, mk: enforce_bandwidth(
+            lat, s, z, aq, total_budget=b, force="ref", task_mask=mk)).lower(
+                {"route": i32, "r": i32, "p": i32, "v": i32}, f32, f32,
+                jax.ShapeDtypeStruct((), jnp.float32), mask).as_text()
+        funcs = dict(re.findall(
+            r"func\.func (?:public|private) @([\w.]+)\((.*?)\n  }",
+            text, re.S))
+        assert "main" in funcs and "c6_tail" in funcs, sorted(funcs)
+        assert "scatter" not in text
+        # the functions reachable from main without entering c6_tail
+        seen, todo = set(), ["main"]
+        while todo:
+            name = todo.pop()
+            if name in seen or name == "c6_tail":
+                continue
+            seen.add(name)
+            todo += re.findall(r"call @([\w.]+)\(", funcs[name])
+        op = '"stablehlo.gather"('
+        gathers = {name: funcs[name].count(op) for name in seen}
+        assert sum(gathers.values()) == 1, gathers
+        main = funcs["main"]
+        assert 0 <= main.find(op) < main.find("stablehlo.while")
 
 
 def test_route_windowed_jit_matches_eager_golden():

@@ -122,15 +122,11 @@ def test_routing_kernel_compiles_for_v5e(name, m, one_chip,
     assert "tpu_custom_call" in compiled.as_text(), name
 
 
-def test_decide_program_for_v5e_keeps_its_stage_scopes(one_chip,
-                                                       no_persistent_cache):
-    """The whole decide program at fleet size, as the chip compiles it: the
-    three routing kernels it runs, and every operation that carries the
-    program's metadata under one of the five stage scopes (what a device
-    trace of the chip attributes its time by)."""
+@pytest.fixture(scope="module")
+def decide_v5e_text(one_chip, no_persistent_cache):
+    """The whole decide program at fleet size, compiled as the chip
+    compiles it (optimized HLO text)."""
     import dataclasses
-
-    from test_spans import unscoped_program_ops
 
     from repro.serving.policy import Observation, make_policy
     from repro.serving.session import _decide_step
@@ -146,8 +142,32 @@ def test_decide_program_for_v5e_keeps_its_stage_scopes(one_chip,
     abstract = lambda tree: jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.asarray(x).dtype,
                                        sharding=one_chip), tree)
-    text = _decide_step.lower(abstract(policy), abstract(policy.init(m)),
+    return _decide_step.lower(abstract(policy), abstract(policy.init(m)),
                               abstract(obs)).compile().as_text()
+
+
+def test_decide_program_for_v5e_keeps_its_stage_scopes(decide_v5e_text):
+    """The whole decide program at fleet size, as the chip compiles it: the
+    three routing kernels it runs, and every operation that carries the
+    program's metadata under one of the five stage scopes (what a device
+    trace of the chip attributes its time by)."""
+    from test_spans import unscoped_program_ops
+
+    text = decide_v5e_text
     for kernel in ("%gate_cell", "%ccg_solve", "%c6_tail"):
         assert kernel in text, kernel
     assert unscoped_program_ops(text) == []
+
+
+def test_decide_program_for_v5e_repair_loop_has_no_gather_or_scatter(
+        decide_v5e_text):
+    """On the chip every data-dependent gather or scatter is a slow op of
+    its own; the C6 repair's passes run none.  The repair's one gather is
+    the hoisted route panel, outside its loop."""
+    import re
+
+    ops = re.findall(r'= \S+ (gather|scatter)\(.*?op_name="([^"]*)"',
+                     decide_v5e_text)
+    in_repair = [(kind, name) for kind, name in ops if "r2e.repair" in name]
+    assert in_repair == [("gather", "jit(_decide_step)/r2e.repair/"
+                                    "r2e.repair/gather")], in_repair
