@@ -14,15 +14,16 @@ The full forward runs over a whole sequence at once in float32 with
 matrix product takes e4m3 operands (per-output-channel weight scales,
 per-row activation scales) with float32 accumulation.
 
-Weights are drawn from the seed's key the way the served pools draw theirs:
-one key per parameter leaf, split in the order of the parameter tree,
-normal(0, 1) times each leaf's scale (embedding 1, input projections
-1/sqrt(fan_in), output projections 1/sqrt(fan_in * 2 * layers), the untied
-head 1/sqrt(d_model)); norms 1, biases 0.
+Weights are the benchmark's data, drawn from the seed's key as the
+published models initialise theirs: every matrix (embedding, projections,
+the untied head) normal(0, ``initializer_range``), norms 1, biases 0; one
+key per leaf, split in the order of the parameter tree, in one jitted
+call.  Each value is rounded to bfloat16, the published checkpoints'
+type, so that it is held exactly in whatever float type the served pool
+keeps it in, and the reference computes on the same numbers.
 """
 from __future__ import annotations
 
-import math
 from functools import partial
 
 import jax
@@ -34,50 +35,71 @@ F8_MAX = 448.0
 
 
 def leaf_specs(c: dict) -> dict:
-    """{path: (shape, init, scale)} of the parameter tree, nested as the
-    served model nests it (one stacked layer segment)."""
+    """{path: (shape, init)} of the parameter tree, nested as the served
+    model nests it (one stacked layer segment)."""
     d, h, kv, hd = c["hidden_size"], c["num_attention_heads"], \
         c["num_key_value_heads"], c["head_dim"]
     f, n, v = c["intermediate_size"], c["num_hidden_layers"], c["vocab_size"]
-    nrm = lambda shape, s: (shape, "normal", s)
-    attn = {"wq": nrm((n, d, h * hd), d ** -0.5),
-            "wk": nrm((n, d, kv * hd), d ** -0.5),
-            "wv": nrm((n, d, kv * hd), d ** -0.5),
-            "wo": nrm((n, h * hd, d), (h * hd) ** -0.5 / math.sqrt(2 * n))}
+    attn = {"wq": ((n, d, h * hd), "normal"),
+            "wk": ((n, d, kv * hd), "normal"),
+            "wv": ((n, d, kv * hd), "normal"),
+            "wo": ((n, h * hd, d), "normal")}
     if c.get("attention_bias", False):
-        attn.update(bq=((n, h * hd), "zeros", 0.0),
-                    bk=((n, kv * hd), "zeros", 0.0),
-                    bv=((n, kv * hd), "zeros", 0.0))
+        attn.update(bq=((n, h * hd), "zeros"), bk=((n, kv * hd), "zeros"),
+                    bv=((n, kv * hd), "zeros"))
     if c.get("qk_norm", False):
-        attn.update(q_norm=((n, hd), "ones", 0.0), k_norm=((n, hd), "ones", 0.0))
+        attn.update(q_norm=((n, hd), "ones"), k_norm=((n, hd), "ones"))
     layer = {"attn": attn,
-             "mlp": {"w_gate": nrm((n, d, f), d ** -0.5),
-                     "w_up": nrm((n, d, f), d ** -0.5),
-                     "w_down": nrm((n, f, d), f ** -0.5 / math.sqrt(2 * n))},
-             "norm1": {"scale": ((n, d), "ones", 0.0)},
-             "norm2": {"scale": ((n, d), "ones", 0.0)}}
-    embed = {"tok": nrm((v, d), 1.0)}
+             "mlp": {"w_gate": ((n, d, f), "normal"),
+                     "w_up": ((n, d, f), "normal"),
+                     "w_down": ((n, f, d), "normal")},
+             "norm1": {"scale": ((n, d), "ones")},
+             "norm2": {"scale": ((n, d), "ones")}}
+    embed = {"tok": ((v, d), "normal")}
     if not c["tie_word_embeddings"]:
-        embed["out"] = nrm((d, v), d ** -0.5)
-    return {"embed": embed, "final_norm": {"scale": ((d,), "ones", 0.0)},
+        embed["out"] = ((d, v), "normal")
+    return {"embed": embed, "final_norm": {"scale": ((d,), "ones")},
             "segments": [{"pos0": layer}]}
 
 
-def make_weights(c: dict, key):
-    """The parameter tree from ``key`` (float32, on the device)."""
-    is_leaf = lambda x: isinstance(x, tuple) and len(x) == 3 \
-        and isinstance(x[1], str)
-    leaves, tree = jax.tree_util.tree_flatten(leaf_specs(c), is_leaf=is_leaf)
+def _is_spec(x) -> bool:
+    return isinstance(x, tuple) and len(x) == 2 and isinstance(x[1], str)
+
+
+@partial(jax.jit, static_argnames=("c", "dtypes"))
+def _draw(key, *, c, dtypes):
+    c = dict(c)
+    leaves, tree = jax.tree_util.tree_flatten(leaf_specs(c), is_leaf=_is_spec)
     keys = jax.random.split(key, len(leaves))
+    std = float(c["initializer_range"])
     out = []
-    for (shape, init, scale), k in zip(leaves, keys):
+    for (shape, init), k, dt in zip(leaves, keys, dtypes):
         if init == "zeros":
-            out.append(jnp.zeros(shape, jnp.float32))
+            w = jnp.zeros(shape, jnp.float32)
         elif init == "ones":
-            out.append(jnp.ones(shape, jnp.float32))
+            w = jnp.ones(shape, jnp.float32)
         else:
-            out.append(jax.random.normal(k, shape, jnp.float32) * scale)
+            w = jax.random.normal(k, shape, jnp.float32) * std
+        out.append(w.astype(jnp.bfloat16).astype(dt))
     return jax.tree_util.tree_unflatten(tree, out)
+
+
+def weight_shapes(c: tuple):
+    """The parameter tree's ``jax.ShapeDtypeStruct``s in float32."""
+    return jax.eval_shape(partial(make_weights, c), jax.random.PRNGKey(0))
+
+
+def make_weights(c: tuple, key, dtypes=None):
+    """The parameter tree from ``key``, on the device, in one jitted call:
+    float32 leaves, or each leaf in its type from ``dtypes`` (a pytree of
+    dtypes of the same structure).  ``c`` is the model's configuration as
+    a hashable tuple of items."""
+    n = len(jax.tree_util.tree_leaves(leaf_specs(dict(c)), is_leaf=_is_spec))
+    if dtypes is None:
+        flat = (jnp.dtype(jnp.float32),) * n
+    else:
+        flat = tuple(jnp.dtype(d) for d in jax.tree_util.tree_leaves(dtypes))
+    return _draw(key, c=c, dtypes=flat)
 
 
 def _q8(x, axis):

@@ -9,8 +9,19 @@ A segment becomes a request exactly as ``ServeSession.dispatch`` makes it:
 ``16 * (1 + r)`` prompt tokens ``(i * 131 + j) mod vocab`` for camera i,
 ``decode_tokens`` greedy tokens.  The harness submits them to the
 session's executor and steps it itself, so that the executor's per-call
-statistics (which ``dispatch`` computes on the device) stay out of the
-timed loop.
+statistics stay out of the timed loop: ``dispatch`` computes them on the
+device with ``jnp.quantile``, one compile per request count.  The cell
+counts each pool's decode steps in the window (a pool that is not idle
+before a scheduling step decodes in it, as every segment decodes at least
+once).
+
+The pools serve the benchmark's weights (``pool_weights``): drawn from
+the seed at the published ``initializer_range`` and put in place of each
+pool's own draw once it is built, so that every layer moves the served
+tokens.  (The program's own draw ties a unit-scale embedding to the edge's
+head: each edge step then repeats its input token, whatever its layers
+do.)  Every seed serves one fleet (``fleet``): the same segments, in an
+order of its own.
 
 Loops: ``open`` -- round k is due ``k * round_period_s`` after the window
 opens; a segment's latency runs from its round's due time to its
@@ -21,13 +32,15 @@ The check runs the plain reference (``bench/ref/qwen_ref.py``) over a
 sample of finished segments drawn from the seed (the longest prompts among
 them), each prompt with its served tokens, and reads the widest gap by
 which a served token's logit lies below the reference's best at its
-position, per pool.  The routed decisions themselves are checked in the
-router cells.
+position, per pool, and compares every window round's routed decisions
+and gate scores with the router's reference (``systems/router.py``).
 """
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import gc
+import sys
 import time
 
 import numpy as np
@@ -53,6 +66,48 @@ def model_config(name: str, c: dict):
         compute_dtype=c["compute_dtype"], param_dtype=c["torch_dtype"])
 
 
+#: the seed of the one fleet that every run of a pool cell serves
+FLEET_SEED = 1
+
+
+def fleet(mix: dict, cameras: int, d_feature: int, seed: int):
+    """A run's round bank and the seed of its gate's weights.
+
+    Every run serves one fleet of the mix, drawn from ``FLEET_SEED`` (each
+    camera's rounds and the gate's weights), with its cameras in an order
+    drawn from the run's seed: the router then gives every seed the same
+    segments in another order, so that a seed changes the order of the
+    pools' work and not its amount."""
+    bank = traffic_gen.round_bank(mix, cameras, d_feature, FLEET_SEED)
+    order = np_rng(seed, "traffic.cameras").permutation(cameras)
+    return dataclasses.replace(bank, z=bank.z[:, order], aq=bank.aq[:, order],
+                               dx=bank.dx[:, order]), FLEET_SEED
+
+
+def pool_weights(pool, c: dict, key) -> None:
+    """Give a built pool the benchmark's weights (``qwen_ref.make_weights``,
+    drawn from ``key`` in one jitted call, each leaf in the type the pool
+    keeps it in), in place of the pool's own draw, which is dropped first
+    so that one copy is held at a time."""
+    import jax
+
+    from ref import qwen_ref
+
+    rc = ref_config(c)
+    held = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), pool.params)
+    want = jax.tree_util.tree_map(
+        lambda w, h: jax.ShapeDtypeStruct(w.shape, h.dtype),
+        qwen_ref.weight_shapes(rc), held)
+    if want != held:
+        raise ValueError(f"the {pool.name} pool's parameter tree is not the "
+                         f"reference's: {held} != {want}")
+    dtypes = jax.tree_util.tree_map(lambda h: h.dtype, held)
+    pool.params = None
+    pool.params = qwen_ref.make_weights(rc, key, dtypes)
+    jax.block_until_ready(pool.params)
+
+
 def pool_groups(cfg: dict) -> dict:
     """{tier: (name, configuration group)}: edge = 0, cloud = 1."""
     return {0: ("edge", cfg["edge_model"]), 1: ("cloud", cfg["cloud_model"])}
@@ -75,15 +130,19 @@ class Cell:
         self.m = int(mix["cameras"])
         self.d = feature_dim()
         self.decode_tokens = int(mix["decode_tokens"])
+        if self.decode_tokens < 2:
+            raise ValueError("decode steps are counted for segments that "
+                             "decode at least once: decode_tokens >= 2")
         dep = dict(cfg["deployment"],
                    total_bw_mbps=cfg["uplink_mbps_per_camera"] * self.m)
         self.deployment = dep
         gate = cfg["gate"]
         gcfg = GateConfig(d_feature=self.d, d_hidden=gate["d_hidden"],
                           var_window=gate["var_window"])
+        self.bank, self.gate_seed = fleet(mix, self.m, self.d, seed)
         policy = make_policy(
             "r2evid", system_config({"deployment": dep}), gate_cfg=gcfg,
-            gate_params=gate_weights(seed, self.d, gate["d_hidden"]),
+            gate_params=gate_weights(self.gate_seed, self.d, gate["d_hidden"]),
             rcfg=RouterConfig(**cfg["router"]))
         if pools is None:
             pools = {}
@@ -91,15 +150,15 @@ class Cell:
                 pools[tier] = ModelPool(model_config(name, c),
                                         jax_key(seed, f"pool.{name}"),
                                         name=name)
-                jax.block_until_ready(pools[tier].params)
+                pool_weights(pools[tier], c, jax_key(seed, f"weights.{name}"))
         self.session = ServeSession(policy, self.m, force="auto", pools=pools)
         self.vocab = {t: p.cfg.vocab_size for t, p in pools.items()}
-        self.bank = traffic_gen.round_bank(mix, self.m, self.d, seed)
         self.k = 0
         self.completions = []          # (round, due, Completion)
         self.decisions = []            # per round served: ((4, M), tau (M,))
         self.window_rounds: list[int] = []
         ex = self.session.executor
+        self.decode_steps = dict.fromkeys(ex.execs, 0)
         self.n_res = len(dep["resolutions"])
         # every shape this traffic can use: each prompt length at each
         # prefill batch pad, each slot count a prefill can fill, one decode
@@ -161,6 +220,8 @@ class Cell:
         with span("bench.dispatch"):
             ex.submit(self._requests(route, r))
             while not ex.idle:
+                for t, e in ex.execs.items():
+                    self.decode_steps[t] += not e.idle
                 with span("bench.step"):
                     ex.step()
         for t, e in ex.execs.items():
@@ -173,6 +234,7 @@ class Cell:
         open_loop = self.mix["loop"] == "open"
         drain_s = float(self.mix.get("drain_s", 60.0))
         self.completions.clear()
+        self.decode_steps = dict.fromkeys(self.decode_steps, 0)
         route_s = []
         t0 = time.perf_counter()
         rounds = 0
@@ -204,6 +266,7 @@ class Cell:
                  "decoded": len(c.ids)}
                 for k, due, c in self.completions]
         attempted = rounds * self.m
+        report_service(segs, self.decode_steps)
         return Record(
             window_s=window_s, attempted=attempted,
             failed=attempted - len(segs), rounds=rounds, segments=segs,
@@ -211,6 +274,7 @@ class Cell:
             tokens_in_window=sum(s["tokens"] for s in segs
                                  if s["finish"] <= t0 + window_s),
             extra={"cameras": self.m, "t0": t0, "end": end,
+                   "decode_steps": dict(self.decode_steps),
                    "configs": {t: c for t, (_, c) in
                                pool_groups(self.cfg).items()}})
 
@@ -254,7 +318,7 @@ class Cell:
         got_dec = np.stack([self.decisions[k][0] for k in picked])
         got_tau = np.stack([self.decisions[k][1] for k in picked])
         want_dec, want_tau, ties = reference(
-            self.router_config(), self.bank, self.seed, self.k, picked,
+            self.router_config(), self.bank, self.gate_seed, self.k, picked,
             got_dec[:, 0])
         out = compare(got_dec, got_tau, want_dec, want_tau, ties, lim)
         for tier, (name, c) in pool_groups(self.cfg).items():
@@ -268,11 +332,30 @@ class Cell:
         return out
 
 
+def report_service(segs, decode_steps):
+    """Each round's service time, from its due time to its last completion,
+    on standard error: where a wide spread of the end-to-end metrics comes
+    from (stalls in a few rounds, or every round slower)."""
+    import statistics
+
+    last = {}
+    for s in segs:
+        last[s["round"]] = max(last.get(s["round"], 0.0), s["finish"] - s["due"])
+    if not last:
+        return
+    svc = sorted(last.values())
+    print(f"bench: {len(svc)} rounds served: service median "
+          f"{statistics.median(svc) * 1e3:.4f} ms, max {svc[-1] * 1e3:.4f} ms "
+          f"(rounds in order: "
+          f"{' '.join(f'{last[k] * 1e3:.1f}' for k in sorted(last))}); "
+          f"decode steps per pool {decode_steps}", file=sys.stderr, flush=True)
+
+
 def ref_config(c: dict) -> tuple:
     keys = ("hidden_size", "num_attention_heads", "num_key_value_heads",
             "head_dim", "intermediate_size", "num_hidden_layers",
             "vocab_size", "rms_norm_eps", "rope_theta", "tie_word_embeddings",
-            "attention_bias", "qk_norm")
+            "attention_bias", "qk_norm", "initializer_range")
     return tuple((k, c[k]) for k in keys)
 
 
@@ -285,7 +368,7 @@ def reference_logits(c: dict, seed: int, name: str, rows, fp8=False):
     from ref import qwen_ref
 
     rc = ref_config(c)
-    params = qwen_ref.make_weights(dict(rc), jax_key(seed, f"pool.{name}"))
+    params = qwen_ref.make_weights(rc, jax_key(seed, f"weights.{name}"))
     out = []
     batch = 8
     for i in range(0, len(rows), batch):
@@ -318,7 +401,7 @@ def control(cell, rec: Record) -> dict:
     from systems.router import compare, reference
 
     lim = cell.cfg["limits"]
-    args = (cell.router_config(), cell.bank, cell.seed, cell.k,
+    args = (cell.router_config(), cell.bank, cell.gate_seed, cell.k,
             cell.window_rounds)
     want_dec, want_tau, ties = reference(*args)
     got_dec, got_tau, _ = reference(*args, mm=router_ref.mm_high)

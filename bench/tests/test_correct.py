@@ -12,27 +12,32 @@ import small
 
 ROUTER = "fleet4096.congested"
 POOLS = "edgecloud-qwen.live"
+BACKLOG = "edgecloud-qwen.backlog"
 
 
 def _passes(checks: dict) -> bool:
     return all(c["value"] <= c["limit"] for c in checks.values())
 
 
-@pytest.mark.parametrize("seed", [3, 2**31 + 4, 2**33 + 5])
-def test_router_control_is_not_correct(seed):
-    mod, cell, rec = small.cell(ROUTER, seed)
+def _control_fails(workload: str, seed: int):
+    mod, cell, rec = small.cell(workload, seed)
     sound = cell.check(rec)
     assert _passes(sound), sound
     ctl = mod.control(cell, rec)
     assert not _passes(ctl), (ctl, sound)
+
+
+@pytest.mark.parametrize("seed", [3, 2**31 + 4, 2**33 + 5])
+def test_router_control_is_not_correct(seed):
+    _control_fails(ROUTER, seed)
 
 
 def test_pools_control_is_not_correct():
-    mod, cell, rec = small.cell(POOLS, 2**31 + 6)
-    sound = cell.check(rec)
-    assert _passes(sound), sound
-    ctl = mod.control(cell, rec)
-    assert not _passes(ctl), (ctl, sound)
+    _control_fails(POOLS, 2**31 + 6)
+
+
+def test_backlog_control_is_not_correct():
+    _control_fails(BACKLOG, 2**32 + 6)
 
 
 def _flip_first_route(orig):
@@ -77,6 +82,25 @@ def _token_altered(orig):
     return "decode_slab", decode_slab
 
 
+def _slab_unchanged(orig):
+    def decode_slab(self, slab, last_ids):
+        kept = jax.tree_util.tree_map(jnp.copy, slab)
+        ids, _ = orig(self, slab, last_ids)
+        return ids, kept
+    return "decode_slab", decode_slab
+
+
+def _edge_slab_unchanged(orig):
+    """The edge pool alone returns its slab unchanged; the cloud is sound."""
+    def decode_slab(self, slab, last_ids):
+        if self.name != "edge":
+            return orig(self, slab, last_ids)
+        kept = jax.tree_util.tree_map(jnp.copy, slab)
+        ids, _ = orig(self, slab, last_ids)
+        return ids, kept
+    return "decode_slab", decode_slab
+
+
 def _prefill_half(orig):
     def prefill_batch(self, tokens):
         ids, cache = orig(self, tokens)
@@ -91,7 +115,8 @@ def test_pools_sound_run_is_correct():
     assert out["failed"] == 0 and out["attempted"] > 0
 
 
-@pytest.mark.parametrize("fault", [_token_altered, _prefill_half])
+@pytest.mark.parametrize("fault", [_token_altered, _prefill_half,
+                                   _slab_unchanged, _edge_slab_unchanged])
 def test_pools_faults_are_not_correct(monkeypatch, fault):
     from repro.serving.pools import ModelPool
 
@@ -99,3 +124,6 @@ def test_pools_faults_are_not_correct(monkeypatch, fault):
     monkeypatch.setattr(ModelPool, name, fn)
     out = small.run(POOLS, 2**31 + 9)
     assert out["correct"] is False, out["checks"]
+    if fault is _edge_slab_unchanged:
+        gap = out["checks"]["edge_logit_gap"]
+        assert gap["value"] > gap["limit"], out["checks"]

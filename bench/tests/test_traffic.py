@@ -53,3 +53,17 @@ def test_rounds_hold_the_same_spread_of_work():
 def test_open_loop_due_times():
     spec = harness.traffic("live")
     assert [traffic_gen.due_time(spec, k) for k in range(3)] == [0.0, 1.0, 2.0]
+
+
+def test_a_fleet_seed_gives_every_seed_the_same_cameras():
+    from systems.routed_pools import FLEET_SEED, fleet
+
+    key = lambda bank: sorted(map(tuple, np.concatenate(
+        [bank.z.T, bank.aq.T, bank.dx.transpose(1, 0, 2).reshape(16, -1)], 1)))
+    for mix in ("live", "backlog"):
+        spec = dict(harness.traffic(mix), bank_rounds=3)
+        (a, ga), (b, gb) = (fleet(spec, 16, 5, 2**31 + 1),
+                            fleet(spec, 16, 5, 2**33))
+        assert ga == gb == FLEET_SEED
+        assert key(a) == key(b)                    # the same cameras ...
+        assert not np.array_equal(a.z, b.z)        # ... in another order
