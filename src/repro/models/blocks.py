@@ -1,4 +1,4 @@
-"""Decoder block assembly: attn / rglru / ssm mixers + (optional) MLP/MoE."""
+"""Decoder block assembly: attn / mla / rglru / ssm mixers + (optional) MLP/MoE."""
 from __future__ import annotations
 
 from typing import Optional
@@ -8,6 +8,7 @@ import jax.numpy as jnp
 from repro.models.attention import attn_forward, attn_specs
 from repro.models.config import ModelConfig
 from repro.models.layers import Ctx, rmsnorm, rmsnorm_specs
+from repro.models.mla import mla_cache_specs, mla_forward, mla_specs
 from repro.models.mlp import mlp_forward, mlp_specs
 from repro.models.moe import moe_forward, moe_specs
 from repro.models.params import ParamSpec
@@ -15,19 +16,24 @@ from repro.models.rglru import rglru_forward, rglru_specs
 from repro.models.ssm import ssm_forward, ssm_specs
 
 
-def block_specs(cfg: ModelConfig, kind: str, serve: bool = False) -> dict:
+def block_specs(cfg: ModelConfig, kind: str, serve: bool = False,
+                dense: bool = False) -> dict:
+    """``dense``: a dense MLP even where the model has experts (its leading
+    ``first_k_dense`` layers)."""
     specs = {"norm1": rmsnorm_specs(cfg.d_model)}
     if kind == "attn":
         specs["attn"] = attn_specs(cfg)
+    elif kind == "mla":
+        specs["attn"] = mla_specs(cfg)
     elif kind == "rglru":
         specs["rglru"] = rglru_specs(cfg)
     elif kind == "ssm":
         specs["ssm"] = ssm_specs(cfg)
     else:
         raise ValueError(kind)
-    if kind in ("attn", "rglru"):
+    if kind != "ssm":
         specs["norm2"] = rmsnorm_specs(cfg.d_model)
-        if cfg.moe is not None:
+        if cfg.moe is not None and not dense:
             specs["mlp"] = moe_specs(cfg, quantized=serve and cfg.quant_experts_serve)
         else:
             specs["mlp"] = mlp_specs(cfg)
@@ -51,6 +57,8 @@ def block_cache_specs(cfg: ModelConfig, kind: str, batch: int, seq_len: int) -> 
             "k": ParamSpec((batch, c, kv, hd), ax, dtype=dt, init="zeros"),
             "v": ParamSpec((batch, c, kv, hd), ax, dtype=dt, init="zeros"),
         }
+    if kind == "mla":
+        return mla_cache_specs(cfg, batch, attn_cache_len(cfg, seq_len))
     if kind == "ssm":
         di, st, cw = cfg.d_inner, cfg.ssm.d_state, cfg.ssm.d_conv
         return {
@@ -76,16 +84,21 @@ def block_apply(
     length=None,
     cache: Optional[dict] = None,
     emit_cache: bool = False,
+    dense: bool = False,
 ):
-    """Returns (x, new_cache_or_None, aux_loss)."""
+    """Returns (x, new_cache_or_None, aux_loss, expert counts): the counts
+    are (2,) int32, the rows the held experts computed and the held experts
+    that got any (zero without experts)."""
     cfg = ctx.cfg
     aux = jnp.zeros((), jnp.float32)
+    counts = jnp.zeros((2,), jnp.int32)
     h = rmsnorm(p["norm1"], x, cfg.norm_eps)
 
-    if kind == "attn":
+    if kind in ("attn", "mla"):
         c = dict(cache, length=length) if cache is not None else None
         out_len = attn_cache_len(cfg, x.shape[1]) if emit_cache else None
-        y, new_cache = attn_forward(ctx, p["attn"], h, positions=positions, cache=c, cache_out_len=out_len)
+        fwd = attn_forward if kind == "attn" else mla_forward
+        y, new_cache = fwd(ctx, p["attn"], h, positions=positions, cache=c, cache_out_len=out_len)
     elif kind == "rglru":
         c = dict(cache, length=length) if cache is not None else None
         y, new_cache = rglru_forward(ctx, p["rglru"], h, cache=c)
@@ -99,12 +112,12 @@ def block_apply(
         new_cache.pop("length", None)
     x = x + y
 
-    if kind in ("attn", "rglru"):
+    if kind != "ssm":
         h2 = rmsnorm(p["norm2"], x, cfg.norm_eps)
-        if cfg.moe is not None:
-            y2, aux = moe_forward(ctx, p["mlp"], h2)
+        if cfg.moe is not None and not dense:
+            y2, aux, counts = moe_forward(ctx, p["mlp"], h2)
         else:
             y2 = mlp_forward(ctx, p["mlp"], h2, activation=cfg.mlp_activation)
         x = x + y2
 
-    return x, new_cache, aux
+    return x, new_cache, aux, counts

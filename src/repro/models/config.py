@@ -12,6 +12,35 @@ class MoEConfig:
     d_expert: int
     capacity_factor: float = 1.25
     min_capacity: int = 16
+    # routing: "softmax" over the chosen logits (Mixtral), or "sigmoid"
+    # scores chosen with a selection-only bias (DeepSeek-V3 noaux_tc), the
+    # chosen scores normalised over the top-k and scaled by routed_scale
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    n_shared: int = 0              # shared experts: one SwiGLU, n_shared * d_expert wide
+    # the chip's share under expert parallelism: this layer holds experts
+    # [expert_offset, expert_offset + n_held) of num_experts (0 = all)
+    n_held: int = 0
+    expert_offset: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.n_held or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3): keys and values come
+    from a kv_lora_rank latent shared by all heads, plus one rotary key of
+    qk_rope_head_dim per position; queries are projected directly."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,10 +78,13 @@ class ModelConfig:
     rope_theta: float = 10000.0
     mrope: bool = False                    # qwen2-vl multimodal rope
     mrope_sections: tuple[int, int, int] = (16, 24, 24)
-    # layer mixture; pattern repeats over layers: entries in {attn, rglru, ssm}
+    # layer mixture; pattern repeats over layers: entries in {attn, mla, rglru, ssm}
     layer_pattern: tuple[str, ...] = ("attn",)
+    # the first k layers carry a dense MLP of width d_ff ahead of the MoE ones
+    first_k_dense: int = 0
     # sub-modules
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     rglru: Optional[RGLRUConfig] = None
     # embeddings
@@ -125,7 +157,7 @@ class ModelConfig:
             n += self.vocab_size * self.d_model
         if not self.tie_embeddings:
             n += self.vocab_size * self.d_model
-        for kind in self.layer_kinds():
+        for i, kind in enumerate(self.layer_kinds()):
             n += self.d_model  # pre-mixer norm
             if kind == "attn":
                 qkv = self.d_model * self.head_dim * (self.num_heads + 2 * self.num_kv_heads)
@@ -135,6 +167,13 @@ class ModelConfig:
                     n += self.head_dim * (self.num_heads + 2 * self.num_kv_heads)
                 if self.qk_norm:
                     n += 2 * self.head_dim
+            elif kind == "mla":
+                m, h = self.mla, self.num_heads
+                n += self.d_model * h * m.qk_head_dim                          # q
+                n += self.d_model * (m.kv_lora_rank + m.qk_rope_head_dim)      # kv_a
+                n += m.kv_lora_rank                                            # kv_a norm
+                n += m.kv_lora_rank * h * (m.qk_nope_head_dim + m.v_head_dim)  # kv_b
+                n += h * m.v_head_dim * self.d_model                           # o
             elif kind == "ssm":
                 d_in, r, s = self.d_inner, self.dt_rank, self.ssm.d_state
                 n += self.d_model * 2 * d_in            # in_proj
@@ -150,14 +189,17 @@ class ModelConfig:
                 n += 2 * (w * w + w)                    # recurrence/input gates
                 n += w                                  # Lambda param
                 n += w * self.d_model                   # out proj
-            if kind == "attn" or kind == "rglru":
+            if kind != "ssm":
                 # MLP follows attention/rglru mixers (ssm blocks are mixer-only)
                 n += self.d_model  # pre-mlp norm
-                if self.moe is not None:
+                if self.moe is not None and i >= self.first_k_dense:
                     e = self.moe
                     n += self.d_model * e.num_experts   # router
+                    if e.scoring == "sigmoid":
+                        n += e.num_experts              # selection bias
                     ff = 3 * self.d_model * e.d_expert
-                    n += (e.num_experts if not active_only else e.top_k) * ff
+                    n += (e.held if not active_only else e.top_k) * ff
+                    n += e.n_shared * ff                # shared experts
                 else:
                     n += 3 * self.d_model * self.d_ff
         n += self.d_model  # final norm

@@ -11,8 +11,9 @@ from repro.models.layers import Ctx
 from repro.models.params import ParamSpec
 
 
-def mlp_specs(cfg: ModelConfig) -> dict:
-    d, f = cfg.d_model, cfg.d_ff
+def mlp_specs(cfg: ModelConfig, width: int = 0) -> dict:
+    """SwiGLU weights of width ``width`` (0: the model's d_ff)."""
+    d, f = cfg.d_model, width or cfg.d_ff
     s_in = d ** -0.5
     s_out = f ** -0.5 / math.sqrt(2 * cfg.num_layers)
     return {

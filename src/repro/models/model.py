@@ -4,7 +4,12 @@ Layers are grouped into *segments*: the repeating ``layer_pattern`` unit is
 stacked ``n_repeat`` times and driven by ``jax.lax.scan`` (one compiled body
 per segment — essential to keep HLO size and CPU compile time bounded for the
 512-device dry-run).  A trailing remainder (e.g. recurrentgemma's 38 = 12x3+2)
-forms a second, shorter segment.
+forms a second, shorter segment, and leading dense layers of an MoE model
+(``first_k_dense``) a segment of their own ahead of the pattern.
+
+A model with experts keeps two counters in its decode cache (``counters``):
+the rows its held experts computed and the held experts that got any,
+summed over decode steps and layers on the device.
 """
 from __future__ import annotations
 
@@ -21,18 +26,27 @@ from repro.models.layers import Ctx, embed_specs, embed_tokens, output_weights, 
 from repro.models.params import ParamSpec, tree_map_specs
 
 
+#: the decode cache's expert counters, in the order block_apply counts them
+EXPERT_COUNTERS = ("expert_rows", "experts_hit")
+
+
 # ---------------------------------------------------------------------------
 # Segments
 # ---------------------------------------------------------------------------
-def build_segments(cfg: ModelConfig) -> list[tuple[tuple[str, ...], int]]:
-    pattern = tuple(cfg.layer_pattern)
-    m = len(pattern)
-    full, rem = divmod(cfg.num_layers, m)
-    segs: list[tuple[tuple[str, ...], int]] = []
+def build_segments(cfg: ModelConfig) -> list[tuple[tuple[str, ...], int, bool]]:
+    """[(pattern, repeats, dense)]: ``dense`` segments carry a dense MLP
+    where the model has experts."""
+    k = cfg.first_k_dense if cfg.moe is not None else 0
+    segs: list[tuple[tuple[str, ...], int, bool]] = []
+    if k:
+        segs.append((tuple(cfg.layer_kind(i) for i in range(k)), 1, True))
+    m = len(cfg.layer_pattern)
+    pattern = tuple(cfg.layer_kind(k + i) for i in range(m))
+    full, rem = divmod(cfg.num_layers - k, m)
     if full:
-        segs.append((pattern, full))
+        segs.append((pattern, full, False))
     if rem:
-        segs.append((pattern[:rem], 1))
+        segs.append((pattern[:rem], 1, False))
     return segs
 
 
@@ -45,9 +59,9 @@ def _stack_specs(specs: dict, n: int) -> dict:
 
 def model_specs(cfg: ModelConfig, serve: bool = False) -> dict:
     segments = []
-    for pattern, n in build_segments(cfg):
+    for pattern, n, dense in build_segments(cfg):
         seg = {
-            f"pos{i}": _stack_specs(block_specs(cfg, kind, serve=serve), n)
+            f"pos{i}": _stack_specs(block_specs(cfg, kind, serve=serve, dense=dense), n)
             for i, kind in enumerate(pattern)
         }
         segments.append(seg)
@@ -60,48 +74,58 @@ def model_specs(cfg: ModelConfig, serve: bool = False) -> dict:
 
 def cache_specs(cfg: ModelConfig, batch: int, seq_len: int) -> dict:
     segments = []
-    for pattern, n in build_segments(cfg):
+    for pattern, n, _ in build_segments(cfg):
         seg = {
             f"pos{i}": _stack_specs(block_cache_specs(cfg, kind, batch, seq_len), n)
             for i, kind in enumerate(pattern)
         }
         segments.append(seg)
-    return {
+    out = {
         "length": ParamSpec((), (), dtype=jnp.int32, init="zeros"),
         "segments": segments,
     }
+    if cfg.moe is not None:
+        out["counters"] = {
+            name: ParamSpec((), (), dtype=jnp.int32, init="zeros")
+            for name in EXPERT_COUNTERS
+        }
+    return out
 
 
 # ---------------------------------------------------------------------------
 # Backbone forward
 # ---------------------------------------------------------------------------
-def _segment_forward(ctx: Ctx, pattern, seg_params, x, *, positions, length, seg_cache, emit_cache):
+def _segment_forward(ctx: Ctx, pattern, seg_params, x, *, positions, length, seg_cache,
+                     emit_cache, dense=False):
     cfg = ctx.cfg
 
     def body(x_carry, xs):
         layer_p, layer_c = xs
         new_c = {}
         aux_total = jnp.zeros((), jnp.float32)
+        counts_total = jnp.zeros((2,), jnp.int32)
         for i, kind in enumerate(pattern):
             c = layer_c[f"pos{i}"] if layer_c is not None else None
-            x_carry, nc, aux = block_apply(
+            x_carry, nc, aux, counts = block_apply(
                 ctx, kind, layer_p[f"pos{i}"], x_carry,
                 positions=positions, length=length, cache=c, emit_cache=emit_cache,
+                dense=dense,
             )
             if nc is not None:
                 new_c[f"pos{i}"] = nc
             aux_total = aux_total + aux
+            counts_total = counts_total + counts
         x_carry = ctx.constrain(x_carry, "batch", "act_seq_sp", "act_embed")
-        return x_carry, (new_c, aux_total)
+        return x_carry, (new_c, aux_total, counts_total)
 
     if cfg.remat and ctx.mode == "train":
         body = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
 
     xs = (seg_params, seg_cache)
-    x, (new_cache, aux) = jax.lax.scan(body, x, xs)
+    x, (new_cache, aux, counts) = jax.lax.scan(body, x, xs)
     if not new_cache:
         new_cache = None
-    return x, new_cache, jnp.sum(aux)
+    return x, new_cache, jnp.sum(aux), jnp.sum(counts, axis=0)
 
 
 def forward(
@@ -143,15 +167,18 @@ def forward(
 
     new_segments = []
     aux_total = jnp.zeros((), jnp.float32)
-    for seg_idx, (pattern, n) in enumerate(build_segments(cfg)):
+    counts_total = jnp.zeros((2,), jnp.int32)
+    for seg_idx, (pattern, n, dense) in enumerate(build_segments(cfg)):
         seg_params = params["segments"][seg_idx]
         seg_cache = cache["segments"][seg_idx] if cache is not None else None
-        x, new_seg, aux = _segment_forward(
+        x, new_seg, aux, counts = _segment_forward(
             ctx, pattern, seg_params, x,
             positions=positions, length=length, seg_cache=seg_cache, emit_cache=emit_cache,
+            dense=dense,
         )
         new_segments.append(new_seg)
         aux_total = aux_total + aux
+        counts_total = counts_total + counts
 
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
 
@@ -159,6 +186,11 @@ def forward(
     if any(s is not None for s in new_segments):
         new_len = (length + s) if length is not None else jnp.asarray(s, jnp.int32)
         new_cache = {"length": new_len, "segments": new_segments}
+        if cache is not None and "counters" in cache:
+            new_cache["counters"] = {
+                name: cache["counters"][name] + counts_total[i]
+                for i, name in enumerate(EXPERT_COUNTERS)
+            }
     return x, new_cache, aux_total
 
 

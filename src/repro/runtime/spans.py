@@ -11,6 +11,25 @@ program names its spans ``r2e.*``:
                   and the output buffers' allocation, whose runtime events
                   nest inside it on the same thread line
 
+The model pools' programs carry named scopes (``jax.named_scope``, in the
+compiled HLO's ``op_name`` paths, so a device trace's operations map to
+them through the program's HLO text):
+
+  ``mla.attend``   latent attention (``models/mla.py``): projections, the
+                   latent cache write, scores and values
+  ``moe.route``    the expert router: scores, selection, weights
+  ``moe.experts``  the held experts' compute: dispatch, the grouped
+                   matmuls (``ragged_dot`` when serving), combine
+  ``moe.shared``   the shared experts
+
+and an expert model's decode cache holds two device-side counters
+(``models.model.EXPERT_COUNTERS``), summed over decode steps and layers
+and read once when wanted (``ModelPool.counters``), with no host sync per
+step:
+
+  ``expert_rows``  (token, choice) rows the held experts computed
+  ``experts_hit``  held experts, per layer and step, that got any row
+
 Spans are off by default; off, :func:`span` is one flag test returning a
 shared no-op context.  An operator turns them on inside a profiled window::
 

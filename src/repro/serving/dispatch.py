@@ -264,9 +264,13 @@ class DispatchExecutor:
     ``step()`` round-robins one scheduling iteration across the tiers so no
     pool serializes behind another; ``serve(requests)`` is the submit+drain
     convenience the session's ``dispatch`` shim calls.
+
+    ``n_slots``: each pool's slab size -- one int for every pool, a
+    ``{tier: slots}`` mapping, or None for each pool's own ``n_slots``
+    (16 unless the pool says otherwise).
     """
 
-    def __init__(self, pools: dict, *, n_slots: int = 16,
+    def __init__(self, pools: dict, *, n_slots=None,
                  max_prefill_len: int = 48, max_prefill_batch: int = 8,
                  feedback_floor: float = 0.25, clock=time.perf_counter):
         if not 0.0 < feedback_floor <= 1.0:
@@ -274,8 +278,12 @@ class DispatchExecutor:
                              f"got {feedback_floor}")
         self.pools = pools
         self.feedback_floor = feedback_floor
+        if n_slots is None:
+            n_slots = {tier: pool.n_slots for tier, pool in pools.items()}
+        elif isinstance(n_slots, int):
+            n_slots = dict.fromkeys(pools, n_slots)
         self.execs = {
-            tier: PoolExecutor(pool, n_slots=n_slots,
+            tier: PoolExecutor(pool, n_slots=n_slots[tier],
                                max_prefill_len=max_prefill_len,
                                max_prefill_batch=max_prefill_batch,
                                clock=clock)

@@ -98,16 +98,22 @@ def _insert_slab_impl(slab, cache, slots):
     segments = jax.tree_util.tree_map(put, slab["segments"],
                                       cache["segments"])
     length = slab["length"].at[slots].set(cache["length"])
-    return {"length": length, "segments": segments}
+    # the slab's own leaves (an expert model's counters) pass through
+    return dict(slab, length=length, segments=segments)
 
 
 _insert_slab = jax.jit(_insert_slab_impl, donate_argnums=(0,))
 
 
 class ModelPool:
-    def __init__(self, cfg: ModelConfig, rng=None, name: str = "pool"):
+    """``n_slots``: the cache-slot slab this pool decodes over when an
+    executor is not told otherwise (``DispatchExecutor``'s ``n_slots``)."""
+
+    def __init__(self, cfg: ModelConfig, rng=None, name: str = "pool",
+                 n_slots: int = 16):
         self.cfg = cfg
         self.name = name
+        self.n_slots = n_slots
         self.ctx = Ctx(cfg=cfg)
         if rng is None:
             rng = jax.random.PRNGKey(0)
@@ -144,11 +150,19 @@ class ModelPool:
         """A fixed slab of ``n_slots`` cache rows sized for prompts up to
         ``max_prefill_len`` tokens plus the model's decode headroom.  The
         scalar cache ``length`` becomes a ``(n_slots,)`` vector — per-slot
-        progress, so rows at different depths co-batch in one decode step."""
+        progress, so rows at different depths co-batch in one decode step.
+        An expert model's slab also holds its expert counters
+        (``models.model.EXPERT_COUNTERS``), summed over the decode steps
+        on the device; :meth:`counters` reads them."""
         specs = cache_specs(self.cfg, n_slots, max_prefill_len)
         slab = tree_map_specs(lambda s: jnp.zeros(s.shape, s.dtype), specs)
         slab["length"] = jnp.zeros((n_slots,), jnp.int32)
         return slab
+
+    @staticmethod
+    def counters(slab) -> dict:
+        """The slab's expert counters on the host ({} without experts)."""
+        return {k: int(v) for k, v in jax.device_get(slab.get("counters", {})).items()}
 
     def prefill_batch(self, tokens):
         """Prefill one bucketed-length batch.  Returns (first decoded ids

@@ -557,6 +557,9 @@ class ServeSession:
     pools : dict, optional
         Tier -> :class:`~repro.serving.pools.ModelPool` live endpoints;
         ``dispatch`` maps a routed solution's token workloads onto them.
+    n_slots : int or dict, optional
+        Each pool's cache-slot slab: one int for every pool, or
+        ``{tier: slots}``; by default each pool's own ``n_slots`` (16).
     admission : AdmissionConfig, optional
         Enable the slot-pool churn path: ``n_streams`` becomes the slot
         capacity M_cap and ``run`` expects ``arrive_n`` / ``depart`` traces
@@ -578,7 +581,8 @@ class ServeSession:
                  hedge: tuple | None = None,
                  admission: AdmissionConfig | None = None,
                  hierarchical: bool = False,
-                 force: str | None = None, pools=None, state=None):
+                 force: str | None = None, pools=None, state=None,
+                 n_slots=None):
         if force is not None and hasattr(policy, "force"):
             policy = dataclasses.replace(policy, force=force)
         sim = sim or SimConfig()
@@ -596,6 +600,7 @@ class ServeSession:
         self.mesh = mesh
         self.mesh_axis = mesh_axis
         self.pools = pools
+        self.n_slots = n_slots
         self._executor = None
         self.finetune = finetune
         self.hedge = hedge
@@ -882,9 +887,11 @@ class ServeSession:
         from repro.serving.dispatch import DispatchExecutor
 
         # slab sized for the largest fidelity the router can choose:
-        # dispatch sizes prompts as 16·(1+r) with r < n_res
+        # dispatch sizes prompts as 16·(1+r) with r < n_res; n_slots per
+        # tier as DispatchExecutor takes it (None: each pool's own)
         return DispatchExecutor(
-            self.pools, max_prefill_len=16 * self.sys_cfg.n_res)
+            self.pools, n_slots=self.n_slots,
+            max_prefill_len=16 * self.sys_cfg.n_res)
 
     @property
     def executor(self):

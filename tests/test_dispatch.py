@@ -82,6 +82,32 @@ def test_executor_matches_serial_oracle(pools):
     assert toks == sum(len(r.tokens) + r.decode_tokens for r in reqs)
 
 
+def test_pools_of_different_slot_counts_co_serve_and_match_serial_oracle(pools):
+    """A 16-slot edge slab and a 64-slot cloud slab, set per tier, serve one
+    request set together as the serial path does; an int still sets every
+    pool, and by default each pool keeps its own count (16)."""
+    reqs = _mixed_requests(pools, m=40, seed=3)
+    want = serve_serial_oracle(
+        pools, [dataclasses.replace(r) for r in reqs])
+    ex = DispatchExecutor(pools, n_slots={0: 16, 1: 64}, max_prefill_batch=8)
+    assert {t: e.n_slots for t, e in ex.execs.items()} == {0: 16, 1: 64}
+    assert ex.execs[1].slab["length"].shape == (64,)
+    ex.serve(reqs)
+    got = {c.stream: c.ids
+           for t in ex.execs for c in ex.execs[t].completions}
+    assert set(got) == set(want)
+    for s in want:
+        np.testing.assert_array_equal(got[s], want[s],
+                                      err_msg=f"stream {s} ids diverge")
+    assert {t: e.n_slots for t, e in
+            DispatchExecutor(pools, n_slots=4).execs.items()} == {0: 4, 1: 4}
+    assert {t: e.n_slots for t, e in
+            DispatchExecutor(pools).execs.items()} == {0: 16, 1: 16}
+    sess = ServeSession(make_policy("r2evid", SYS), 4, pools=pools,
+                        n_slots={0: 16, 1: 64})
+    assert sess.executor.execs[1].n_slots == 64
+
+
 def test_join_leave_does_not_perturb_decodes(pools):
     """A segment's decoded ids are independent of which other segments share
     its decode batch: serve one request alone, then co-batched with segments

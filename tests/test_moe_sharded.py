@@ -33,11 +33,11 @@ SCRIPT = textwrap.dedent(
     rules = make_rules(mesh, "train")
     ctx_sharded = Ctx(cfg=cfg, rules=rules)
     with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else mesh:
-        y_sharded, aux_s = jax.jit(lambda p_, x_: moe_forward(ctx_sharded, p_, x_))(p, x)
+        y_sharded, aux_s, _ = jax.jit(lambda p_, x_: moe_forward(ctx_sharded, p_, x_))(p, x)
 
     # reference: single-group (G=1) dispatch, no mesh
     ctx_plain = Ctx(cfg=cfg)
-    y_plain, aux_p = jax.jit(lambda p_, x_: moe_forward(ctx_plain, p_, x_))(p, x)
+    y_plain, aux_p, _ = jax.jit(lambda p_, x_: moe_forward(ctx_plain, p_, x_))(p, x)
 
     # G=4 grouping changes which tokens drop ONLY when capacity binds; the
     # smoke config uses capacity_factor=8 (no drops), so outputs must agree.
